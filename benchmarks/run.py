@@ -70,6 +70,9 @@ def main(argv=None) -> int:
     unknown = [k for k in keys if k not in MODULES]
     if unknown:
         p.error(f"unknown module(s) {unknown}; choose from {sorted(MODULES)}")
+    from repro import compile_cache
+
+    compile_cache.enable()
     print("name,us_per_call,derived")
     for k in keys:
         t0 = time.time()

@@ -34,6 +34,7 @@ import dataclasses
 import hashlib
 import json
 import os
+import sys
 import time
 from typing import Any, Callable, Iterable, Mapping
 
@@ -314,6 +315,18 @@ def _run_cell(
     return value, time.perf_counter() - t0
 
 
+def _accelerator_in_use() -> bool:
+    """Whether this process has initialised a JAX backend other than the
+    CPU — searched families may have run the device polish or the JAX
+    circulant pricer while the topologies were built."""
+    if "jax" not in sys.modules:
+        return False
+    from jax._src import xla_bridge
+
+    return xla_bridge.backends_are_initialized() and any(
+        p != "cpu" for p in xla_bridge.backends())
+
+
 def _parallel_cells(
     names: list[str],
     graphs_out: Mapping[str, Graph],
@@ -327,13 +340,22 @@ def _parallel_cells(
     Returns None when the pool cannot be set up at all — no fork start
     method (the registry's lambda workloads only travel by inheritance),
     or unpicklable graphs/factory/params — so the caller falls back to the
-    serial loop.  Workload exceptions are NOT swallowed: they propagate
-    exactly like the serial path would raise them.
+    serial loop.  Raises ``RuntimeError`` instead when this process holds a
+    JAX accelerator backend: forking it is unsafe, and a quiet serial run
+    would hide that the request could not be met.  Workload exceptions are
+    NOT swallowed: they propagate exactly like the serial path would raise
+    them.
     """
     import concurrent.futures
     import multiprocessing
     import pickle
 
+    if _accelerator_in_use():
+        raise RuntimeError(
+            "run_experiment(parallel=True) cannot fork workers: this process "
+            "has initialised a JAX accelerator backend and holds its chip, "
+            "which a forked child must not inherit; run with parallel=False, "
+            "or build the topologies in a process that has not touched JAX")
     if "fork" not in multiprocessing.get_all_start_methods():
         return None
     try:  # probe the task payloads once, up front
@@ -396,8 +418,10 @@ def run_experiment(
     the workload registry and the spec build cache is reused across them).
     Values are bit-identical to the serial path — both run the same
     :func:`_run_cell` — and per-cell timings/provenance are preserved; the
-    pool silently falls back to serial when it cannot be set up (no fork
-    start method, unpicklable graphs/factory/params), while workload
+    pool falls back to serial when it cannot be set up (no fork start
+    method, unpicklable graphs/factory/params), refuses with
+    ``RuntimeError`` once this process has initialised a JAX accelerator
+    backend (a forked child must not inherit the chip), and workload
     errors propagate either way.  ``parallel=None`` (the default) reads
     the ``REPRO_PARALLEL`` env var (``"1"`` enables).
     """

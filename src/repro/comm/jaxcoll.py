@@ -32,7 +32,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..compat import shard_map
 from ..core.graphs import Graph
 from ..core import collectives as C
 
@@ -59,13 +58,6 @@ def ring_perm(n: int, order: Sequence[int] | None = None, reverse: bool = False)
     return pairs
 
 
-def _axis_size(axis_name: str) -> int:
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    # jax < 0.5: psum of a python scalar is folded to the static axis size
-    return jax.lax.psum(1, axis_name)
-
-
 def _my_ring_index(axis_name: str, order: Sequence[int] | None, n: int) -> jax.Array:
     rank = jax.lax.axis_index(axis_name)
     if order is None:
@@ -81,7 +73,7 @@ def ring_reduce_scatter(x: jax.Array, axis_name: str,
     x's leading dim must be divisible by n.  Returns chunk of shape
     (x.shape[0] // n, ...), the fully-reduced chunk this rank owns.
     """
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     assert x.shape[0] % n == 0
     chunks = x.reshape(n, x.shape[0] // n, *x.shape[1:])
     pos = _my_ring_index(axis_name, order, n)
@@ -101,7 +93,7 @@ def ring_reduce_scatter(x: jax.Array, axis_name: str,
 def ring_allgather(x: jax.Array, axis_name: str,
                    order: Sequence[int] | None = None) -> jax.Array:
     """Per-device chunk -> concatenation of all chunks (ring, n-1 steps)."""
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     pos = _my_ring_index(axis_name, order, n)
     perm = ring_perm(n, order)
     out = jnp.zeros((n, *x.shape), x.dtype)
@@ -118,7 +110,7 @@ def ring_allgather(x: jax.Array, axis_name: str,
 def ring_allreduce(x: jax.Array, axis_name: str,
                    order: Sequence[int] | None = None) -> jax.Array:
     """Bandwidth-optimal ring allreduce; x identical-shaped on all ranks."""
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     lead = x.shape[0] if x.ndim else 1
     pad = (-lead) % n
     xp = jnp.pad(x.reshape(lead, -1), ((0, pad), (0, 0))) if x.ndim else x.reshape(1, 1)
@@ -130,7 +122,7 @@ def ring_allreduce(x: jax.Array, axis_name: str,
 
 def recursive_doubling_allreduce(x: jax.Array, axis_name: str) -> jax.Array:
     """log2(n) rounds of XOR-partner exchange (latency-optimal, small msgs)."""
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     assert n & (n - 1) == 0, "recursive doubling needs power-of-two axis"
     mask = 1
     while mask < n:
@@ -147,7 +139,7 @@ def int8_ring_allreduce(x: jax.Array, axis_name: str,
     Wire bytes ~ x.nbytes/4 + scales.  Quantization error per hop is bounded
     by scale/254; after n-1 hops relative error stays ~1e-2 for n<=32 (tested).
     """
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     lead = x.shape[0]
     pad = (-lead) % n
     xp = jnp.pad(x.reshape(lead, -1).astype(jnp.float32), ((0, pad), (0, 0)))
@@ -191,7 +183,7 @@ def flood_bcast(x: jax.Array, axis_name: str, g: Graph, root: int = 0) -> jax.Ar
     Devices other than root contribute zeros; after ecc(root) rounds every
     rank holds root's value.  Rounds come from core.collectives.bcast_flood.
     """
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     assert g.n == n
     sched = C.bcast_flood(n, 0.0, g, root=root)
     rank = jax.lax.axis_index(axis_name)
@@ -231,10 +223,11 @@ def run_on_axis(fn, mesh: Mesh, axis: str, *args):
         out = fn(*[x[0] for x in xs], axis_name=axis)
         return out[None]
 
-    wrapped = shard_map(
+    wrapped = jax.shard_map(
         inner,
         mesh=mesh,
         in_specs=tuple(P(axis) for _ in args),
         out_specs=P(axis),
+        check_vma=False,
     )
     return wrapped(*args)

@@ -1,20 +1,22 @@
-"""``engine="pallas"`` — the device word-packed sweep (``kernels.bfs_sweep``).
+"""``engine="pallas"`` — the device BFS sweep (``kernels.bfs_sweep``).
 
-Same packed-frontier algorithm as the host bitset engine, but the level loop
-runs inside one Pallas kernel with the frontier/visited/distance state in
-VMEM, using 32-bit words (TPU vector units have no 64-bit lanes).  On this
-CPU-only container the kernel executes in interpret mode (the
-``flash_attention``/``ssd_scan`` convention) so CI exercises it; on a real
-TPU/GPU the launcher flips ``set_interpret(False)`` and the identical kernel
-lowers to the device.
+The same level-synchronous BFS as the host bitset engine, run as a Pallas
+TPU kernel: each grid cell keeps a (n, 128-source) distance block resident
+in VMEM for the whole level loop and reads neighbour indices as scalars from
+SMEM.  Whether the kernels run compiled or in Pallas interpret mode is
+resolved in one place, ``_default_interpret``: compiled exactly when JAX's
+default backend is a TPU, interpret mode elsewhere (the CPU test suite),
+unless ``REPRO_PALLAS_INTERPRET`` says otherwise.  The model-zoo kernels in
+``repro.kernels.ops`` use the same resolver.
 
-``sharded_rows_totals`` is the replica-polish entry point: R stacked
-neighbour tables are priced in one ``shard_map`` over the replica axis, so
-each device sweeps its replicas' graphs locally and only the per-replica
-(total, max) scalars come home.
+``sharded_rows_totals`` and ``sharded_delta_state`` are the replica-polish
+entry points: R stacked neighbour tables are priced in one ``shard_map``
+over the replica axis, so each device sweeps its replicas' graphs locally
+and only per-replica scalars (and, for the delta path, the state) come home.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import os
 
@@ -23,46 +25,53 @@ import numpy as np
 from .base import Engine
 
 # None = unresolved: the first get_interpret() call resolves it from the
-# REPRO_PALLAS_INTERPRET env override, falling back to platform auto-detect
-# (interpret on CPU hosts, compiled on TPU/GPU backends)
+# REPRO_PALLAS_INTERPRET env override, falling back to the platform
 _INTERPRET: bool | None = None
+# devices the replica axis shards over; None = every local device
+_DEVICES: tuple | None = None
 _CACHE: dict = {}
 
 
 def _default_interpret() -> bool:
     """Resolve the interpret default: ``REPRO_PALLAS_INTERPRET`` wins
     (1/true/on → interpret, 0/false/off → compiled), otherwise compiled
-    mode exactly when jax reports an accelerator backend — so device
-    runners flip modes without code edits."""
+    mode exactly when JAX's default backend is a TPU — the only platform
+    the kernels are written for."""
     env = os.environ.get("REPRO_PALLAS_INTERPRET")
     if env is not None and env.strip() != "":
         return env.strip().lower() not in ("0", "false", "no", "off")
-    jax = _jax()
-    if jax is None:
-        return True
-    try:
-        backend = jax.default_backend()
-    except Exception:  # pragma: no cover - defensive: broken jax install
-        return True
-    return backend not in ("tpu", "gpu", "cuda", "rocm")
+    return _jax().default_backend() != "tpu"
 
 
 def set_interpret(v: bool | None) -> None:
-    """Flip Pallas interpret mode for the BFS sweep (False on real TPU);
-    ``None`` re-resolves the default (env override / platform detect)."""
+    """Force Pallas interpret mode on (True) or off (False); ``None``
+    re-resolves the default (env override, then platform)."""
     global _INTERPRET
     _INTERPRET = v
     _CACHE.clear()
 
 
 def get_interpret() -> bool:
-    """Whether the sweep currently runs in Pallas interpret mode (the
+    """Whether the Pallas kernels currently run in interpret mode (the
     benchmarks record this: interpret-mode timings measure interpreter
     overhead, not device performance)."""
     global _INTERPRET
     if _INTERPRET is None:
         _INTERPRET = _default_interpret()
     return _INTERPRET
+
+
+@contextlib.contextmanager
+def replica_devices(devices):
+    """Shard the replica axis over ``devices`` instead of every local
+    device inside the block — e.g. one device, to check that a multi-device
+    run prices the same trajectory."""
+    global _DEVICES
+    prev, _DEVICES = _DEVICES, tuple(devices)
+    try:
+        yield
+    finally:
+        _DEVICES = prev
 
 
 def _jax():
@@ -89,56 +98,63 @@ class PallasEngine(Engine):
     def rows_bfs(self, ev, sources: np.ndarray) -> np.ndarray:
         from ...kernels import bfs_sweep
 
-        return bfs_sweep.bfs_rows(ev.nbr, sources, ev.sentinel,
-                                  interpret=get_interpret())
+        return bfs_sweep.bfs_rows(ev.nbr, sources, ev.sentinel)
 
 
 # ------------------------------------------------------------------------------
 # Replica-sharded batched pricing (large_search replica polish)
 # ------------------------------------------------------------------------------
 
-def _mesh_axis(r: int) -> int:
-    """Largest divisor of ``r`` that fits the local device count — the
-    replica axis length (1 on a single-device host: same math, one shard)."""
+def _mesh(r: int):
+    """1-D ``("r",)`` mesh over the largest divisor of ``r`` that fits the
+    replica devices (one device on a single-chip host: same math, one
+    shard)."""
+    from jax.sharding import Mesh
+
+    devs = _DEVICES or tuple(_jax().devices())
+    nd = max(d for d in range(1, min(r, len(devs)) + 1) if r % d == 0)
+    return Mesh(np.asarray(devs[:nd]), ("r",))
+
+
+def _sweep(use_pallas: bool, interpret: bool, n: int, kmax: int, lanes: int,
+           sentinel: int):
+    """(bs, n, kmax) tables, (bs, lanes) sources -> (bs, lanes, n) rows:
+    the Pallas kernel or its vmapped jnp twin."""
     jax = _jax()
-    nd = len(jax.devices())
-    return max(d for d in range(1, min(r, nd) + 1) if r % d == 0)
+    from ...kernels import bfs_sweep
+
+    if use_pallas:
+        return lambda nb, src: bfs_sweep._pallas_sweep(
+            nb.shape[0], n, kmax, lanes, sentinel, interpret)(nb, src)
+    return jax.vmap(functools.partial(bfs_sweep.sweep_rows_ref,
+                                      sentinel=sentinel))
 
 
-def _sharded_fn(r: int, n: int, kmax: int, sw_pad: int, bw: int, m: int,
+def _sharded_fn(r: int, n: int, kmax: int, lanes: int, m: int,
                 sentinel: int, use_pallas: bool):
     jax = _jax()
     import jax.numpy as jnp
-    from jax.sharding import Mesh, PartitionSpec as P
+    from jax.sharding import PartitionSpec as P
 
-    from ... import compat
-    from ...kernels import bfs_sweep
-
-    key = ("sharded", r, n, kmax, sw_pad, bw, m, sentinel, use_pallas)
+    interpret = get_interpret()
+    mesh = _mesh(r)
+    key = ("sharded", r, n, kmax, lanes, m, sentinel, use_pallas, interpret,
+           tuple(mesh.devices.flat))
     fn = _CACHE.get(key)
     if fn is not None:
         return fn
+    sweep = _sweep(use_pallas, interpret, n, kmax, lanes, sentinel)
 
-    def per_shard(nb, vm, F0):
-        if use_pallas:
-            rows = bfs_sweep._pallas_sweep(
-                nb.shape[0], n, kmax, sw_pad, bw, sentinel, get_interpret()
-            )(nb, vm, F0)
-        else:
-            rows = jax.vmap(
-                functools.partial(bfs_sweep.sweep_rows_ref, sentinel=sentinel)
-            )(nb, vm, F0)
-        rows = rows[:, :m, :]
+    def per_shard(nb, src):
+        rows = sweep(nb, src)[:, :m, :]
         # per-source sums fit int32 only while n * sentinel <= 2^31 - 1
         # (n <= 46340 with sentinel == n — guarded in sharded_rows_totals);
         # the int64 grand total is finished on the host, where x64 is on
         return (rows.sum(2, dtype=jnp.int32), rows.max((1, 2)))
 
-    nd = _mesh_axis(r)
-    mesh = Mesh(np.asarray(jax.devices()[:nd]), ("r",))
-    fn = jax.jit(compat.shard_map(
-        per_shard, mesh=mesh,
-        in_specs=(P("r"), P("r"), P("r")), out_specs=(P("r"), P("r"))))
+    fn = jax.jit(jax.shard_map(
+        per_shard, mesh=mesh, in_specs=(P("r"), P("r")),
+        out_specs=(P("r"), P("r")), check_vma=False))
     _CACHE[key] = fn
     return fn
 
@@ -167,9 +183,9 @@ def sharded_rows_totals(
         raise NotImplementedError(
             f"device pricing needs n * sentinel <= int32 max (n={n}, "
             f"sentinel={sentinel})")
-    nb, vm, F0, sw_pad, bw = bfs_sweep.pack_batch(nbrs, np.arange(m))
-    rowsums, mx = _sharded_fn(r, n, kmax, sw_pad, bw, m, sentinel,
-                              use_pallas)(nb, vm, F0)
+    nb, src = bfs_sweep.pack_sweep(nbrs, [np.arange(m)] * r)
+    rowsums, mx = _sharded_fn(r, n, kmax, src.shape[1], m, sentinel,
+                              use_pallas)(nb, src)
     return np.asarray(rowsums).sum(1, dtype=np.int64), np.asarray(mx)
 
 
@@ -178,40 +194,36 @@ def sharded_rows_totals(
 # ------------------------------------------------------------------------------
 
 def _sharded_delta_fn(r: int, mprop: int, n: int, kmax: int, s: int,
-                      sw_pad: int, bw: int, mmax: int, amax: int,
-                      sentinel: int, use_pallas: bool):
+                      lanes: int, mmax: int, amax: int, sentinel: int,
+                      use_pallas: bool):
     jax = _jax()
     import jax.numpy as jnp
-    from jax.sharding import Mesh, PartitionSpec as P
+    from jax.sharding import PartitionSpec as P
 
-    from ... import compat
     from ...kernels import bfs_sweep
 
     interpret = get_interpret()
-    key = ("delta", r, mprop, n, kmax, s, sw_pad, bw, mmax, amax, sentinel,
-           use_pallas, interpret)
+    mesh = _mesh(r)
+    key = ("delta", r, mprop, n, kmax, s, lanes, mmax, amax, sentinel,
+           use_pallas, interpret, tuple(mesh.devices.flat))
     fn = _CACHE.get(key)
     if fn is not None:
         return fn
+    sweep = _sweep(use_pallas, interpret, n, kmax, lanes, sentinel)
 
-    def per_shard(base, nb, vm, F0, ids, crow_src, crow_shift, pts_idx,
-                  pmask, add_i, add_j, add_w):
+    def per_shard(base, nb, src, crow_src, crow_shift, pts_idx, pmask, add_i,
+                  add_j, add_w):
         # base is (r_sh, s, n); the proposal arrays are (r_sh * mprop, ...)
         # in replica-major order, so repeating base rows M times lines the
         # two batch layouts up within the shard
         bs = nb.shape[0]
-        if use_pallas:
-            rows = bfs_sweep._pallas_sweep(
-                bs, n, kmax, sw_pad, bw, sentinel, interpret)(nb, vm, F0)
-        else:
-            rows = jax.vmap(functools.partial(
-                bfs_sweep.sweep_rows_ref, sentinel=sentinel))(nb, vm, F0)
+        rows = sweep(nb, src)
         baseb = jnp.repeat(base, mprop, axis=0)
         # merge: re-swept rows replace their representative rows, idle lanes
-        # (id == s, out of range) drop; unaffected rows are provably exact
+        # (src == n, out of range) drop; unaffected rows are provably exact
         merged = jax.vmap(
             lambda bb, rw, ii: bb.at[ii].set(rw, mode="drop")
-        )(baseb, rows, ids)
+        )(baseb, rows, src)
         tmp, crows = jax.vmap(bfs_sweep.patch_prologue)(
             merged, crow_src, crow_shift, pts_idx, pmask, add_i, add_j, add_w)
         if use_pallas:
@@ -222,11 +234,9 @@ def _sharded_delta_fn(r: int, mprop: int, n: int, kmax: int, s: int,
         # int32 row sums: n * sentinel <= 2^31 - 1 guarded by the caller
         return out.sum(2, dtype=jnp.int32), out.max((1, 2)), out
 
-    nd = _mesh_axis(r)
-    mesh = Mesh(np.asarray(jax.devices()[:nd]), ("r",))
-    fn = jax.jit(compat.shard_map(
-        per_shard, mesh=mesh,
-        in_specs=(P("r"),) * 12, out_specs=(P("r"), P("r"), P("r"))))
+    fn = jax.jit(jax.shard_map(
+        per_shard, mesh=mesh, in_specs=(P("r"),) * 10,
+        out_specs=(P("r"), P("r"), P("r")), check_vma=False))
     _CACHE[key] = fn
     return fn
 
@@ -266,11 +276,12 @@ def sharded_delta_state(
         raise NotImplementedError(
             f"device pricing needs n * sentinel <= int32 max (n={n}, "
             f"sentinel={sentinel})")
-    nb, vm, F0, ids, sw_pad, bw = bfs_sweep.pack_delta_batch(
-        nbrs, sources_list, s)
+    # src doubles as the merge scatter index: lane j of proposal i re-sweeps
+    # representative row src[i, j]
+    nb, src = bfs_sweep.pack_sweep(nbrs, sources_list)
     patch = bfs_sweep.pack_patch(patches, s)
     mmax, amax = patch[2].shape[1], patch[4].shape[1]
     rowsums, mx, state = _sharded_delta_fn(
-        r, b // r, n, kmax, s, sw_pad, bw, mmax, amax, sentinel, use_pallas)(
-        np.ascontiguousarray(base), nb, vm, F0, ids, *patch)
+        r, b // r, n, kmax, s, src.shape[1], mmax, amax, sentinel,
+        use_pallas)(np.ascontiguousarray(base), nb, src, *patch)
     return np.asarray(rowsums).sum(1, dtype=np.int64), np.asarray(mx), state

@@ -585,8 +585,9 @@ class SymmetricAPSP:
       neighbour table.  This is the fast no-kernel path at N >= 8192 (and
       uses the C word-packed sweep for the BFS itself when the kernel
       happens to be available).
-    - ``"pallas"`` — the same packed sweep as a Pallas device kernel
-      (``kernels.bfs_sweep``, 32-bit words in VMEM); interpret mode on CPU.
+    - ``"pallas"`` — the same BFS as a Pallas TPU kernel
+      (``kernels.bfs_sweep``, distance blocks in VMEM); interpret mode off
+      a TPU.
     - ``"numpy"`` — the seed dense float32-matmul BFS (``_bfs_rows``); keeps
       an (n, n) float32 adjacency mirror, O(n^2) per BFS level.
 

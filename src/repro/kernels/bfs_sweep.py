@@ -1,37 +1,36 @@
-"""Word-packed BFS frontier sweep as a Pallas kernel (the device engine).
+"""Batched BFS row sweep as a Pallas TPU kernel (the device engine).
 
-This is the same algorithm as ``repro.core.metrics.bitset_bfs_rows`` — the
-frontier/visited sets packed into machine words along the *source* dimension,
-one BFS level advancing every source at once with word-parallel OR/AND-NOT
-sweeps over the padded neighbour table:
+Computes the same hop distances as ``repro.core.metrics.bitset_bfs_rows``
+— one BFS level advancing every source at once over the padded neighbour
+table — for a stack of graphs, each swept from its own source list.
 
-    N[v]  = OR_{u in nbr(v)} F[u]      (gather over the neighbour table)
-    newF  = N & ~V;  V |= newF
+Two implementations share one wire format: ``nb`` (n, kmax) int32 gather
+tables whose pad entries point at the vertex's own row (harmless: a vertex
+already in the frontier is already visited), and ``src`` (lanes,) int32
+source vertices, idle lanes holding ``n`` (no vertex).
 
-— but executed on the accelerator: the whole level loop runs inside one
-``pallas_call`` with the frontier (F), visited (V) and distance state living
-in VMEM for the duration of the sweep, instead of round-tripping numpy
-temporaries through host RAM per level.  Words are **32-bit** (``uint32``):
-TPU vector units have no 64-bit lanes, so the uint64 packing of the host
-bitset engine would not lower — the bit layout here is the little-endian
-lower/upper half split of the host engine's uint64 words, and the resulting
-distances are bit-identical (asserted by the property tests in
-``tests/test_incremental.py``).
+* ``sweep_rows_ref`` is the jnp twin: frontier and visited sets packed into
+  32-bit words along the source axis (the host bitset engine's uint64 words
+  split into lower/upper halves — TPU vector units have no 64-bit lanes),
+  advanced by word-parallel OR/AND-NOT gathers.  It runs on any backend and
+  under ``vmap``.
+* ``_pallas_sweep`` is the TPU kernel.  Grid ``(graph, source block)``;
+  one cell owns ``LANES`` = 128 sources (one int32 vreg row per vertex) and
+  keeps its ``(n, 128)`` distance state resident in VMEM for the whole level
+  loop (4 MiB at n = 8192).  A level is a row loop over vertices: the
+  neighbour indices are read as scalars from SMEM, staged from HBM
+  ``NB_ROWS`` vertices at a time, and each neighbour's distance row is a
+  dynamic single-row load.  Vertex ``v`` is reached at level ``d + 1`` in
+  lane j iff it is unreached and some neighbour sits at exactly ``d``; rows
+  are updated in place, which is safe because a row written during level d
+  holds ``d + 1`` and can never match ``== d``.  The finished block is
+  copied to HBM vertex-major and transposed to ``(lanes, n)`` by XLA.
 
-Grid layout: ``(batch, source word-blocks)``.  Every grid cell owns
-``block_words`` words (``block_words * 32`` sources) of frontier state for
-one graph — source blocks are fully independent BFS problems, so the grid is
-embarrassingly parallel and the per-cell VMEM footprint stays bounded:
-at N = 16384, k = 8, ``block_words = 4`` the cell holds two (n, 4) uint32
-bitsets (256 KB each), the (n, k) neighbour table/mask (1 MB) and a
-(128, n) int32 distance tile (8 MB) — inside the ~16 MB VMEM budget.  The
-batch axis serves the replica-sharded polish tier: `shard_map` splits it
-across devices and each device sweeps its replicas' graphs locally.
-
-``interpret=True`` is the CPU path (this container is CPU-only; CI exercises
-the kernel in interpret mode), mirroring the ``flash_attention``/``ssd_scan``
-convention.  ``sweep_rows_ref`` is the pure-jnp oracle — identical math
-without the Pallas launch, usable on any backend and under ``vmap``.
+Both return exact integer hop counts, with ``sentinel`` for unreachable
+pairs, so they are bit-identical (property tests in ``tests/test_kernels.py``
+and ``tests/test_incremental.py``).  Whether the kernel runs compiled or in
+Pallas interpret mode is the caller's argument, resolved from the platform
+by ``repro.core.engines.pallas_sweep.get_interpret``.
 """
 from __future__ import annotations
 
@@ -39,8 +38,15 @@ import functools
 
 import numpy as np
 
-WORD = 32  # uint32 packing: TPU-safe (no 64-bit vector lanes)
-BLOCK_WORDS = 4  # source words per grid cell (128 sources)
+WORD = 32  # uint32 packing of the jnp twin (no 64-bit vector lanes on TPU)
+LANES = 128  # sources per sweep-kernel grid cell: one int32 vreg row
+NB_ROWS = 1024  # neighbour-table rows staged in SMEM per copy (kmax * 4 KiB)
+# in-kernel "not reached yet": never equal to a level counter (<= n), mapped
+# to the caller's sentinel after the sweep
+UNREACHED = np.int32(np.iinfo(np.int32).max)
+# VMEM the patch kernel's double-buffered blocks may take: half of v5e's
+# 16 MiB default scoped limit, the rest is left to the compiler's temporaries
+PATCH_VMEM_BUDGET = 8 << 20
 # "unreachable" weight for masked patch entries: real hop distances are
 # <= sentinel = n <= 46340, and the patch adds at most two PATCH_INF terms
 # plus one distance (2^21 + n), so int32 arithmetic never overflows while
@@ -49,46 +55,58 @@ PATCH_INF = np.int32(1 << 20)
 
 __all__ = [
     "WORD",
-    "BLOCK_WORDS",
+    "LANES",
     "PATCH_INF",
     "bfs_rows",
     "bfs_rows_batched",
-    "pack_batch",
-    "pack_delta_batch",
-    "pack_frontier",
     "pack_nbr",
     "pack_patch",
+    "pack_sweep",
     "patch_apply_ref",
     "patch_prologue",
+    "source_lanes",
     "sweep_rows_ref",
 ]
 
 _CACHE: dict = {}
 
 
-def pack_nbr(nbr: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
-    """(gather table, validity word-mask) from a padded neighbour table.
-
-    Pad entries (< 0) are redirected to vertex 0 and masked with an all-zero
-    word so the in-kernel gather needs no bounds logic.
-    """
-    valid = nbr >= 0
-    nb = np.where(valid, nbr, 0).astype(np.int32)
-    vm = np.where(valid, np.uint32(0xFFFFFFFF), np.uint32(0))
-    return nb, vm
+def pack_nbr(nbr: np.ndarray) -> np.ndarray:
+    """(..., n, kmax) int32 gather table from padded neighbour tables: pad
+    entries (< 0) point at the vertex's own row, so neither sweep needs a
+    validity mask or bounds logic."""
+    n = nbr.shape[-2]
+    return np.where(nbr >= 0, nbr, np.arange(n)[:, None]).astype(np.int32)
 
 
-def pack_frontier(n: int, sources: np.ndarray, sw_pad: int) -> np.ndarray:
-    """(n, sw_pad) uint32 seed frontier: bit j of word w set at vertex
-    ``sources[w * 32 + j]`` — the 32-bit half-word view of the host bitset
-    engine's uint64 packing."""
-    F0 = np.zeros((n, sw_pad), dtype=np.uint32)
-    m = len(sources)
-    if m:
-        j = np.arange(m)
-        np.bitwise_or.at(F0, (np.asarray(sources, dtype=np.int64), j >> 5),
-                         np.uint32(1) << (j & 31).astype(np.uint32))
-    return F0
+def _pow2(x: int) -> int:
+    """Smallest power of two >= max(x, 1) — pads variable per-iteration
+    shapes (affected-row words, patch endpoints) into a bounded bucket set so
+    the jit/pallas caches stay small."""
+    return 1 << max(0, int(x) - 1).bit_length()
+
+
+def source_lanes(m: int) -> int:
+    """Padded source-lane count for ``m`` sources: a power of two of 32-bit
+    words (the twin's packing; per-iteration delta sweeps then reuse a few
+    compiled shapes), which is a whole number of ``LANES`` blocks once it
+    exceeds one (the kernel's tiling)."""
+    return _pow2(-(-m // WORD)) * WORD
+
+
+def pack_sweep(nbrs: np.ndarray,
+               sources_list) -> tuple[np.ndarray, np.ndarray]:
+    """Pack a (b, n, kmax) neighbour-table stack for the batched sweep,
+    graph r swept from ``sources_list[r]``: returns ``(nb, src)`` in the
+    wire format both sweeps take.  ``src[r, j]`` is the vertex lane j of
+    graph r sweeps from; idle lanes hold ``n``, which seeds nothing and
+    which the delta merge's out-of-range scatter drops."""
+    b, n, _ = nbrs.shape
+    lanes = source_lanes(max((len(x) for x in sources_list), default=0))
+    src = np.full((b, lanes), n, dtype=np.int32)
+    for r, x in enumerate(sources_list):
+        src[r, : len(x)] = x
+    return pack_nbr(nbrs), src
 
 
 def _unpack_bits(words, jnp):
@@ -99,132 +117,184 @@ def _unpack_bits(words, jnp):
     return bits.reshape(n, w * WORD).T.astype(bool)
 
 
-def sweep_rows_ref(nb, vm, F0, sentinel: int):
-    """Pure-jnp packed sweep: (n, kmax) gather table + validity mask and a
-    (n, bw) seed frontier -> (bw*32, n) int32 hop distances.
+def sweep_rows_ref(nb, src, sentinel: int):
+    """Pure-jnp packed sweep: (n, kmax) gather table and (lanes,) source
+    vertices -> (lanes, n) int32 hop distances.
 
-    The jittable oracle for the Pallas kernel (and the `vmap`-able device
-    fallback the replica-sharded polish uses when the Pallas path is off).
+    The jittable oracle for the Pallas kernel, and the ``vmap``-able device
+    path the replica-sharded polish takes when the Pallas kernel is off.
     """
     import jax
     import jax.numpy as jnp
 
-    kmax = nb.shape[1]
-    bw = F0.shape[1]
+    n, kmax = nb.shape
+    lanes = src.shape[0]
+    j = jnp.arange(lanes)
+    bit = jnp.left_shift(jnp.uint32(1), (j % WORD).astype(jnp.uint32))
+    # distinct (vertex, word, bit) triples: the add is an OR; idle lanes
+    # (src == n) fall off the end
+    F0 = jnp.zeros((n, lanes // WORD), jnp.uint32).at[src, j // WORD].add(
+        bit, mode="drop")
     dist0 = jnp.where(_unpack_bits(F0, jnp), 0, sentinel).astype(jnp.int32)
-
-    def cond(st):
-        return st[4]
 
     def body(st):
         d, F, V, dist, _ = st
         N = jnp.zeros_like(F)
-        for j in range(kmax):  # static unroll: kmax = max degree, small
-            N = N | (jnp.take(F, nb[:, j], axis=0) & vm[:, j : j + 1])
+        for k in range(kmax):  # static unroll: kmax = max degree, small
+            N = N | jnp.take(F, nb[:, k], axis=0)
         newF = N & ~V
         d = d + 1
         dist = jnp.where(_unpack_bits(newF, jnp), d, dist)
         return (d, newF, V | newF, dist, jnp.any(newF != jnp.uint32(0)))
 
     st = (jnp.int32(0), F0, F0, dist0, jnp.any(F0 != jnp.uint32(0)))
-    return jax.lax.while_loop(cond, body, st)[3]
+    return jax.lax.while_loop(lambda st: st[4], body, st)[3]
 
 
-def _kernel(nb_ref, vm_ref, f0_ref, dist_ref, *, sentinel):
-    # one grid cell = one (graph, source word-block) pair, state in VMEM
-    dist_ref[0] = sweep_rows_ref(nb_ref[0], vm_ref[0], f0_ref[0], sentinel)
-
-
-def _pallas_sweep(b: int, n: int, kmax: int, sw_pad: int, bw: int,
-                  sentinel: int, interpret: bool):
-    """Compiled batched sweep for (b, n, kmax)/(b, n, sw_pad) inputs."""
+def _sweep_kernel(src_ref, nb_hbm, out_hbm, dist_ref, nb_smem, *, n, kmax,
+                  rows):
+    # one grid cell = one (graph, 128-source block); dist_ref is the
+    # (n_pad, blk) state, nb_smem the staged slice of the neighbour table
     import jax
+    import jax.numpy as jnp
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
-    key = ("pallas", b, n, kmax, sw_pad, bw, sentinel, interpret)
+    r = pl.program_id(0)
+    blk = dist_ref.shape[1]
+    n_chunks = dist_ref.shape[0] // rows
+    dist_ref[...] = jnp.full(dist_ref.shape, UNREACHED, jnp.int32)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, blk), 1)
+
+    def seed(j, carry):
+        v = src_ref[0, 0, 0, j]
+
+        @pl.when(v < n)
+        def _():
+            row = dist_ref[pl.ds(v, 1), :]
+            dist_ref[pl.ds(v, 1), :] = jnp.where(lane == j, 0, row)
+
+        return carry
+
+    jax.lax.fori_loop(0, blk, seed, 0)
+
+    def level(st):
+        d, _ = st
+
+        def chunk(c, acc):
+            pltpu.sync_copy(nb_hbm.at[r, c], nb_smem)
+
+            def vertex(lv, acc):
+                v = c * rows + lv
+                cur = dist_ref[pl.ds(v, 1), :]
+                hit = jnp.zeros((1, blk), jnp.bool_)
+                for k in range(kmax):  # static unroll: kmax = max degree
+                    u = nb_smem[0, lv * kmax + k]
+                    hit = hit | (dist_ref[pl.ds(u, 1), :] == d)
+                new = hit & (cur == UNREACHED)
+                dist_ref[pl.ds(v, 1), :] = jnp.where(new, d + 1, cur)
+                return acc | new.astype(jnp.int32)
+
+            return jax.lax.fori_loop(0, rows, vertex, acc)
+
+        acc = jax.lax.fori_loop(0, n_chunks, chunk,
+                                jnp.zeros((1, blk), jnp.int32))
+        return d + 1, jnp.max(acc) > 0
+
+    jax.lax.while_loop(lambda st: st[1], level,
+                       (jnp.int32(0), jnp.bool_(True)))
+    pltpu.sync_copy(dist_ref.at[pl.ds(0, n)], out_hbm.at[r, pl.program_id(1)])
+
+
+def _pallas_sweep(b: int, n: int, kmax: int, lanes: int, sentinel: int,
+                  interpret: bool):
+    """Compiled batched sweep: (b, n, kmax) tables and (b, lanes) sources
+    -> (b, lanes, n) int32 distances."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    key = ("sweep", b, n, kmax, lanes, sentinel, interpret)
     fn = _CACHE.get(key)
     if fn is not None:
         return fn
-    kernel = functools.partial(_kernel, sentinel=sentinel)
-    fn = pl.pallas_call(
-        kernel,
-        grid=(b, sw_pad // bw),
+    # always whole 128-lane blocks: a narrower VMEM block is lane-padded to
+    # 128 anyway, and its copy-out slice would break the (8, 128) tiling
+    nblk = -(-lanes // LANES)
+    rows = min(n, NB_ROWS)
+    n_pad = -(-n // rows) * rows
+    call = pl.pallas_call(
+        functools.partial(_sweep_kernel, n=n, kmax=kmax, rows=rows),
+        grid=(b, nblk),
         in_specs=[
-            pl.BlockSpec((1, n, kmax), lambda r, i: (r, 0, 0)),
-            pl.BlockSpec((1, n, kmax), lambda r, i: (r, 0, 0)),
-            pl.BlockSpec((1, n, bw), lambda r, i: (r, 0, i)),
+            pl.BlockSpec((1, 1, 1, LANES), lambda r, i: (r, i, 0, 0),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pltpu.HBM),
         ],
-        out_specs=pl.BlockSpec((1, bw * WORD, n), lambda r, i: (r, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, sw_pad * WORD, n), jax.numpy.int32),
+        out_specs=pl.BlockSpec(memory_space=pltpu.HBM),
+        out_shape=jax.ShapeDtypeStruct((b, nblk, n, LANES), jnp.int32),
+        scratch_shapes=[pltpu.VMEM((n_pad, LANES), jnp.int32),
+                        pltpu.SMEM((1, rows * kmax), jnp.int32)],
         interpret=interpret,
     )
-    fn = jax.jit(fn)
+
+    def sweep(nb, src):
+        # pad vertices (n <= v < n_pad) loop to themselves: never reached
+        pad = jnp.broadcast_to(
+            jnp.arange(n, n_pad, dtype=jnp.int32)[None, :, None],
+            (b, n_pad - n, kmax))
+        nbp = jnp.concatenate([nb, pad], axis=1).reshape(
+            b, n_pad // rows, 1, rows * kmax)
+        srcp = jnp.pad(src, ((0, 0), (0, nblk * LANES - lanes)),
+                       constant_values=n)
+        out = call(srcp.reshape(b, nblk, 1, LANES), nbp)
+        out = out.transpose(0, 1, 3, 2).reshape(b, nblk * LANES, n)[:, :lanes]
+        return jnp.where(out == UNREACHED, sentinel, out)
+
+    fn = jax.jit(sweep)
     _CACHE[key] = fn
     return fn
-
-
-def pack_batch(
-    nbrs: np.ndarray,
-    sources: np.ndarray,
-    block_words: int = BLOCK_WORDS,
-) -> tuple[np.ndarray, np.ndarray, np.ndarray, int, int]:
-    """Pack a (b, n, kmax) neighbour-table stack for the batched sweep.
-
-    The one place the word/pad contract lives: returns
-    ``(nb, vm, F0, sw_pad, bw)`` with ``sw_pad`` a multiple of the block
-    width ``bw``, shared by the single-graph, batched and sharded entry
-    points so their layouts can never drift apart.
-    """
-    b, n, kmax = nbrs.shape
-    m = len(sources)
-    sw = max(1, (m + WORD - 1) // WORD)
-    bw = min(block_words, sw)
-    sw_pad = -(-sw // bw) * bw
-    nb = np.empty((b, n, kmax), dtype=np.int32)
-    vm = np.empty((b, n, kmax), dtype=np.uint32)
-    for r in range(b):
-        nb[r], vm[r] = pack_nbr(nbrs[r])
-    F0 = np.ascontiguousarray(np.broadcast_to(
-        pack_frontier(n, sources, sw_pad), (b, n, sw_pad)))
-    return nb, vm, F0, sw_pad, bw
 
 
 def bfs_rows_batched(
     nbrs: np.ndarray,
     sources: np.ndarray,
     sentinel: int,
-    interpret: bool = True,
-    block_words: int = BLOCK_WORDS,
+    interpret: bool | None = None,
 ):
     """Batched device BFS: (b, n, kmax) neighbour tables -> (b, m, n) int32.
 
     All graphs share the same ``sources`` (the representative rows of the
     symmetric polish tier).  Returns a jax array; callers slice/convert.
+    ``interpret=None`` takes the platform's mode (``get_interpret``).
     """
+    if interpret is None:
+        from ..core.engines.pallas_sweep import get_interpret
+
+        interpret = get_interpret()
     b, n, kmax = nbrs.shape
-    m = len(sources)
-    nb, vm, F0, sw_pad, bw = pack_batch(nbrs, sources, block_words)
-    out = _pallas_sweep(b, n, kmax, sw_pad, bw, sentinel, interpret)(nb, vm, F0)
-    return out[:, :m, :]
+    nb, src = pack_sweep(nbrs, [sources] * b)
+    out = _pallas_sweep(b, n, kmax, src.shape[1], sentinel, interpret)(nb, src)
+    return out[:, : len(sources), :]
 
 
 def bfs_rows(
     nbr: np.ndarray,
     sources: np.ndarray,
     sentinel: int,
-    interpret: bool = True,
-    block_words: int = BLOCK_WORDS,
+    interpret: bool | None = None,
 ) -> np.ndarray:
-    """Hop distances from ``sources`` via the Pallas packed sweep, as a
+    """Hop distances from ``sources`` via the Pallas sweep, as a
     (len(sources), n) int32 numpy array — the drop-in device twin of
     ``repro.core.metrics.bitset_bfs_rows`` (bit-identical, sentinel
-    included; any source count works, tail bits simply stay zero)."""
+    included; any source count works, idle lanes are sliced away)."""
     m = len(sources)
     n = nbr.shape[0]
     if m == 0:
         return np.full((0, n), sentinel, dtype=np.int32)
     out = bfs_rows_batched(nbr[None], np.asarray(sources), sentinel,
-                           interpret=interpret, block_words=block_words)
+                           interpret=interpret)
     return np.asarray(out[0])
 
 
@@ -232,51 +302,12 @@ def bfs_rows(
 # Delta sweep: incremental pricing of batched orbit swaps (the device twin of
 # ``metrics.SymmetricAPSP.evaluate_swap``).  The host runs the exact batched
 # lost-parent removal test against its mirrored (dist, npar) state and packs,
-# per proposal, only the *affected* representative rows as the seed frontier;
-# the sweep then repairs those rows on the post-removal graph, the merged
+# per proposal, only the *affected* representative rows as sources; the
+# sweep then repairs those rows on the post-removal graph, the merged
 # state keeps the provably-unchanged rows, and the min-plus insert patch
 # applies the added edges — exact integer hop counts end to end, so the delta
 # path is bit-identical to a full re-sweep (property-tested).
 # ------------------------------------------------------------------------------
-
-def _pow2(x: int) -> int:
-    """Smallest power of two >= max(x, 1) — pads variable per-iteration
-    shapes (affected-row words, patch endpoints) into a bounded bucket set so
-    the jit/pallas caches stay small."""
-    return 1 << max(0, int(x) - 1).bit_length()
-
-
-def pack_delta_batch(
-    nbrs: np.ndarray,
-    sources_list,
-    n_rows: int,
-    block_words: int = BLOCK_WORDS,
-) -> tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray, int, int]:
-    """Pack per-proposal restricted frontiers for the batched delta sweep.
-
-    Unlike ``pack_batch`` (one shared source set broadcast to every graph),
-    each of the b proposals sweeps its own affected-row set.  Returns
-    ``(nb, vm, F0, ids, sw_pad, bw)``: ``ids[r, j]`` is the representative
-    row swept by packed lane j of proposal r, padded with ``n_rows`` so the
-    merge scatter drops the idle lanes.  ``sw_pad`` is bucketed to a power
-    of two (a bounded compile-cache footprint across iterations).
-    """
-    b, n, kmax = nbrs.shape
-    mx = max((len(src) for src in sources_list), default=0)
-    sw = _pow2((mx + WORD - 1) // WORD)
-    bw = min(block_words, sw)
-    sw_pad = -(-sw // bw) * bw
-    nb = np.empty((b, n, kmax), dtype=np.int32)
-    vm = np.empty((b, n, kmax), dtype=np.uint32)
-    F0 = np.empty((b, n, sw_pad), dtype=np.uint32)
-    ids = np.full((b, sw_pad * WORD), n_rows, dtype=np.int32)
-    for r in range(b):
-        nb[r], vm[r] = pack_nbr(nbrs[r])
-        src = np.asarray(sources_list[r], dtype=np.int64)
-        F0[r] = pack_frontier(n, src, sw_pad)
-        ids[r, : len(src)] = src
-    return nb, vm, F0, ids, sw_pad, bw
-
 
 def pack_patch(patches, s: int) -> tuple[np.ndarray, ...]:
     """Pack per-proposal min-plus insert patches for the delta sweep.
@@ -372,10 +403,20 @@ def _patch_kernel(dist_ref, tmp_ref, crows_ref, out_ref, *, mmax):
     out_ref[0] = d
 
 
-def _row_block(s: int, cap: int = 128) -> int:
-    """Largest divisor of ``s`` at most ``cap`` — the patch kernel's row-tile
-    height (keeps the (rb, n) distance tile inside the VMEM budget)."""
-    return max(d for d in range(1, min(s, cap) + 1) if s % d == 0)
+def _patch_rows(s: int, n: int, mmax: int,
+                budget: int = PATCH_VMEM_BUDGET) -> int:
+    """Row-tile height of the patch kernel: the largest divisor of ``s``
+    that meets the (8, 128) tiling rule (a multiple of 8, or all of ``s``)
+    and whose double-buffered blocks — dist and out (rb, n), tmp (rb, mmax),
+    crows (mmax, n), each padded to whole (8, 128) int32 tiles — fit
+    ``budget`` bytes; the smallest legal divisor when none fits."""
+    def tiles(rows: int, cols: int) -> int:
+        return -(-rows // 8) * 8 * -(-cols // 128) * 128 * 4
+
+    legal = [d for d in range(1, s + 1) if s % d == 0 and (d % 8 == 0 or d == s)]
+    fits = [d for d in legal
+            if 2 * (2 * tiles(d, n) + tiles(d, mmax) + tiles(mmax, n)) <= budget]
+    return max(fits) if fits else min(legal)
 
 
 def _pallas_patch(b: int, s: int, n: int, mmax: int, interpret: bool):
@@ -383,7 +424,7 @@ def _pallas_patch(b: int, s: int, n: int, mmax: int, interpret: bool):
     import jax
     from jax.experimental import pallas as pl
 
-    rb = _row_block(s)
+    rb = _patch_rows(s, n, mmax)
     key = ("patch", b, s, n, mmax, rb, interpret)
     fn = _CACHE.get(key)
     if fn is not None:

@@ -82,7 +82,8 @@ def flash_attention_fwd(
     blk_q: int = 128,
     blk_k: int = 128,
     scale: float | None = None,  # pass the UNPADDED hd**-0.5 when hd is padded
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ) -> jax.Array:
     b, h, sq, hd = q.shape
     _, kvh, skv, _ = k.shape

@@ -4,25 +4,21 @@ Model code calls these; they handle layout (b,s,h,hd)<->(b,h,s,hd), head-dim
 padding to the 128-lane MXU (kimi: 112 -> 128), and the inter-chunk state
 scan that completes the SSD algorithm around the intra-chunk kernel.
 
-``interpret`` defaults to True because this container is CPU-only; on real
-TPU the launcher flips ``set_interpret(False)``.
+Interpret mode comes from the one platform resolver the topology kernels
+use, ``repro.core.engines.pallas_sweep.get_interpret``: compiled on a TPU
+backend, Pallas interpret mode elsewhere, unless ``REPRO_PALLAS_INTERPRET``
+says otherwise.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
+from ..core.engines.pallas_sweep import get_interpret
 from . import flash_attention as _fa
 from . import ssd_scan as _ssd
 
-__all__ = ["flash_attention", "ssd_scan", "set_interpret"]
-
-_INTERPRET = True
-
-
-def set_interpret(v: bool) -> None:
-    global _INTERPRET
-    _INTERPRET = v
+__all__ = ["flash_attention", "ssd_scan"]
 
 
 def _pad_hd(x: jax.Array, mult: int = 128) -> tuple[jax.Array, int]:
@@ -51,7 +47,7 @@ def flash_attention(
     # output channels are sliced away below
     o = _fa.flash_attention_fwd(qt, kt, vt, causal=causal, q_offset=q_offset,
                                 blk_q=blk_q, blk_k=blk_k, scale=hd ** -0.5,
-                                interpret=_INTERPRET)
+                                interpret=get_interpret())
     return o[..., :hd].transpose(0, 2, 1, 3)
 
 
@@ -87,7 +83,7 @@ def ssd_scan(
     Br = B.transpose(0, 2, 1, 3).reshape(b * h, s, n)
     Cr = C.transpose(0, 2, 1, 3).reshape(b * h, s, n)
 
-    y_intra, states = _ssd.ssd_intra_chunk(xr, dtr, Ar, Br, Cr, chunk, interpret=_INTERPRET)
+    y_intra, states = _ssd.ssd_intra_chunk(xr, dtr, Ar, Br, Cr, chunk, interpret=get_interpret())
 
     # inter-chunk state scan (linear, cheap) + cross-chunk output term
     dA = (dtr * Ar).reshape(b * h, nc, chunk)

@@ -64,7 +64,7 @@ def ssd_intra_chunk(
     B: jax.Array,   # (bh, s, n)
     C: jax.Array,   # (bh, s, n)
     chunk: int,
-    interpret: bool = True,
+    interpret: bool,
 ) -> tuple[jax.Array, jax.Array]:
     """Returns (y_intra (bh, s, p) fp32, states (bh, nc, p, n) fp32)."""
     bh, s, p = x.shape

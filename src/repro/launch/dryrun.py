@@ -35,7 +35,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..compat import peak_memory_bytes
 from ..configs.base import ARCH_IDS, SHAPES, get_config
 from ..models.zoo import build_model
 from ..optim import make_optimizer
@@ -277,7 +276,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool = False,
             "argument_bytes": ma.argument_size_in_bytes,
             "output_bytes": ma.output_size_in_bytes,
             "temp_bytes": ma.temp_size_in_bytes,
-            "peak_bytes": peak_memory_bytes(ma),
+            "peak_bytes": int(ma.peak_memory_in_bytes),
             "alias_bytes": ma.alias_size_in_bytes,
             "generated_code_bytes": ma.generated_code_size_in_bytes,
         },

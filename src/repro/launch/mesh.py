@@ -20,17 +20,6 @@ import jax
 import numpy as np
 from jax.sharding import Mesh
 
-# jax < 0.5 has no AxisType / axis_types kwarg; explicit Auto only exists on
-# newer versions and is the default there anyway.
-try:
-    from jax.sharding import AxisType
-
-    def _mk_mesh(devs: np.ndarray, axes: tuple[str, ...]) -> Mesh:
-        return Mesh(devs, axes, axis_types=(AxisType.Auto,) * len(axes))
-except ImportError:  # pragma: no cover - version-dependent
-    def _mk_mesh(devs: np.ndarray, axes: tuple[str, ...]) -> Mesh:
-        return Mesh(devs, axes)
-
 
 def make_production_mesh(*, multi_pod: bool = False,
                          device_order: Sequence[int] | None = None) -> Mesh:
@@ -47,7 +36,7 @@ def make_production_mesh(*, multi_pod: bool = False,
         assert sorted(device_order) == list(range(n))
         devs = [devs[i] for i in device_order]
     arr = np.asarray(devs, dtype=object).reshape(shape)
-    return _mk_mesh(arr, axes)
+    return Mesh(arr, axes)
 
 
 def make_test_mesh(shape: tuple[int, ...] = (2, 2, 2),
@@ -55,7 +44,7 @@ def make_test_mesh(shape: tuple[int, ...] = (2, 2, 2),
     """Small host-device mesh for CPU tests (device count flag set by caller)."""
     n = int(np.prod(shape))
     devs = np.asarray(jax.devices()[:n], dtype=object).reshape(shape)
-    return _mk_mesh(devs, axes)
+    return Mesh(devs, axes)
 
 
 def optimized_pod_order(n_pods: int, degree: int = 4, seed: int = 0,

@@ -33,7 +33,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..compat import shard_map
 from ..configs.base import ArchConfig, MoECfg
 from .common import Initializer
 from .sharding import ShardingRules
@@ -243,11 +242,12 @@ def moe_ffn(
         else:
             fn = functools.partial(_moe_shard, m=m, model_axis=model_axis, fsdp_axis=fsdp_axis,
                                    pmean_axes=tuple(mesh.axis_names))
-        y, aux = shard_map(
+        y, aux = jax.shard_map(
             fn,
             mesh=mesh,
             in_specs=(P(b_entry, seq_entry, None), P(None, None), *w_spec),
             out_specs=(P(b_entry, seq_entry, None), P()),
+            check_vma=False,
         )(x, p["router"], p["w1"], p["w3"], p["w2"])
     if m.n_shared_experts:
         from .common import swiglu

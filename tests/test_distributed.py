@@ -158,8 +158,7 @@ b_shard = S.batch_shardings(model, in_specs)
 lowered = jax.jit(step, in_shardings=(st_shard, b_shard)).lower(st_shapes, in_specs)
 compiled = lowered.compile()
 ma = compiled.memory_analysis()
-from repro.compat import peak_memory_bytes
-assert peak_memory_bytes(ma) > 0
+assert ma.peak_memory_in_bytes > 0
 hlo = compiled.as_text()
 assert 'all-reduce' in hlo or 'all-gather' in hlo  # pod/data sync exists
 print('PASS')

@@ -81,6 +81,19 @@ def test_parallel_falls_back_on_unpicklable_factory():
     assert set(exp.values) == {"r", "t"}
 
 
+def test_parallel_refuses_once_an_accelerator_is_initialised(monkeypatch):
+    """A process holding a chip must not fork workers; the request is
+    refused, not quietly run serially."""
+    assert not api._accelerator_in_use()  # the CPU backend never counts
+    monkeypatch.setattr(api, "_accelerator_in_use", lambda: True)
+    with pytest.raises(RuntimeError, match="accelerator"):
+        api.run_experiment({"r": "ring:16", "t": "torus:4x4"}, ["stats"],
+                           parallel=True)
+    ser = api.run_experiment({"r": "ring:16", "t": "torus:4x4"}, ["stats"],
+                             parallel=False)
+    assert set(ser.values) == {"r", "t"}
+
+
 def test_parallel_propagates_workload_errors():
     api.register_workload("test-raises",
                           lambda g, cl, **kw: (_ for _ in ()).throw(

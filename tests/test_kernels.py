@@ -93,7 +93,7 @@ def test_model_paths_match_with_pallas():
 
 def test_bfs_sweep_kernel_matches_jnp_oracle():
     """The Pallas kernel and its pure-jnp twin (sweep_rows_ref) agree
-    bit-exactly, including the word packing helpers."""
+    bit-exactly, including the packing helpers."""
     from repro.core import metrics
     from repro.core.graphs import circulant
     from repro.kernels import bfs_sweep
@@ -101,11 +101,11 @@ def test_bfs_sweep_kernel_matches_jnp_oracle():
     for n, offs, m in [(96, [1, 7], 96), (130, [2, 9, 31], 37), (64, [1, 5], 64)]:
         nbr = metrics._nbr_table(circulant(n, offs).adjacency())
         srcs = np.arange(m)
-        sw_pad = max(1, -(-m // bfs_sweep.WORD))
-        nb, vm = bfs_sweep.pack_nbr(nbr)
-        F0 = bfs_sweep.pack_frontier(n, srcs, sw_pad)
-        oracle = np.asarray(jax.jit(bfs_sweep.sweep_rows_ref, static_argnums=3)(
-            nb, vm, F0, n))[:m]
+        nb, src = bfs_sweep.pack_sweep(nbr[None], [srcs])
+        assert src.shape[1] == bfs_sweep.source_lanes(m)
+        assert (src[0, m:] == n).all()  # idle lanes seed nothing
+        oracle = np.asarray(jax.jit(bfs_sweep.sweep_rows_ref, static_argnums=2)(
+            nb[0], src[0], n))[:m]
         got = bfs_sweep.bfs_rows(nbr, srcs, n)
         assert np.array_equal(got, oracle)
         assert np.array_equal(got, metrics.bitset_bfs_rows(nbr, srcs, n))
