@@ -20,9 +20,8 @@ with its replica axis sharded over four chips against the same spec on a
 one-device mesh, and the ``comm.jaxcoll`` ring and recursive-doubling
 allreduce on a four-device mesh against ``jax.lax.psum``.
 
-Everything runs in this one process.  Earlier lines report the device,
-wall seconds per phase (compiles included) and the compared values; the
-last line of stdout is ``{"ok": true, "device": {...}}``.  Without a TPU,
+Everything runs in this one process.  Earlier lines report the device
+and the compared values of each phase; the last line of stdout is ``{"ok": true, "device": {...}}``.  Without a TPU,
 or on any mismatch or error, the script exits non-zero and prints no such
 line.
 """
@@ -33,7 +32,6 @@ import json
 import math
 import os
 import sys
-import time
 
 SRC = os.path.join(os.path.dirname(os.path.abspath(__file__)), "src")
 sys.path.insert(0, SRC)
@@ -63,12 +61,6 @@ def _same(what: str, got, want) -> None:
         raise AssertionError(f"{what} differs: {got!r} != {want!r}")
 
 
-def _timed(fn):
-    t0 = time.perf_counter()
-    out = fn()
-    return out, time.perf_counter() - t0
-
-
 def phase_polish(interpret: bool = False, **size) -> dict:
     """Pallas replica polish vs the jnp twins, and vs an independent
     recount of the returned graph."""
@@ -77,16 +69,16 @@ def phase_polish(interpret: bool = False, **size) -> dict:
     from repro.core.engines import pallas_sweep
 
     size = {**POLISH, **size}
-    res, t_pallas = _timed(lambda: api.search(polish_spec("pallas", **size)))
+    res = api.search(polish_spec("pallas", **size))
     _same("Pallas interpret mode", pallas_sweep.get_interpret(), interpret)
     if res.device_dispatches <= 0:
         raise AssertionError("the polish made no device dispatch")
-    twin, t_twin = _timed(lambda: api.search(polish_spec(None, **size)))
+    twin = api.search(polish_spec(None, **size))
     _same("polish trajectory (pallas vs jnp twin)", _trajectory(res),
           _trajectory(twin))
     _same("polish graph (pallas vs jnp twin)", res.graph.edges,
           twin.graph.edges)
-    cert, t_cert = _timed(lambda: certify.certify(res.graph))
+    cert = certify.certify(res.graph)
     # integer totals over s or n sources: equal up to the final division
     if not math.isclose(cert.mpl, res.mpl, rel_tol=1e-12):
         raise AssertionError(f"certified MPL {cert.mpl!r} != {res.mpl!r}")
@@ -95,9 +87,7 @@ def phase_polish(interpret: bool = False, **size) -> dict:
             "accepted": res.accepted, "history_len": len(res.history),
             "device_dispatches": res.device_dispatches,
             "evals_delta": res.evals_delta, "evals_full": res.evals_full,
-            "certified_total_hops": cert.total_hops,
-            "seconds_pallas": t_pallas, "seconds_jnp_twin": t_twin,
-            "seconds_certify": t_cert}
+            "certified_total_hops": cert.total_hops}
 
 
 def phase_circulant(**size) -> dict:
@@ -105,13 +95,12 @@ def phase_circulant(**size) -> dict:
     from repro.core import search
 
     size = {**CIRCULANT, **size}
-    a, t_jax = _timed(lambda: search.circulant_search(engine="jax", **size))
-    b, t_np = _timed(lambda: search.circulant_search(engine="numpy", **size))
+    a = search.circulant_search(engine="jax", **size)
+    b = search.circulant_search(engine="numpy", **size)
     for f in ("offsets", "mpl", "diameter", "iterations", "history"):
         _same(f"circulant {f} (jax vs numpy)", getattr(a, f), getattr(b, f))
     return {"offsets": list(a.offsets), "mpl": a.mpl, "diameter": a.diameter,
-            "iterations": a.iterations, "seconds_jax": t_jax,
-            "seconds_numpy": t_np}
+            "iterations": a.iterations}
 
 
 def phase_four_chips(**size) -> dict:
@@ -130,9 +119,9 @@ def phase_four_chips(**size) -> dict:
         raise RuntimeError(f"four devices needed, found {len(devs)}")
     size = {**POLISH, **size}
     spec = polish_spec("pallas", **size)
-    sharded, t_sharded = _timed(lambda: api.search(spec))
+    sharded = api.search(spec)
     with pallas_sweep.replica_devices(devs[:1]):
-        single, t_single = _timed(lambda: api.search(spec))
+        single = api.search(spec)
     _same("polish trajectory (4 devices vs 1)", _trajectory(sharded),
           _trajectory(single))
     _same("polish graph (4 devices vs 1)", sharded.graph.edges,
@@ -149,7 +138,6 @@ def phase_four_chips(**size) -> dict:
             raise AssertionError(f"{fn.__name__} differs from psum")
     return {"mpl": sharded.mpl, "accepted": sharded.accepted,
             "device_dispatches": sharded.device_dispatches,
-            "seconds_4_devices": t_sharded, "seconds_1_device": t_single,
             "allreduce_shape": list(x.shape)}
 
 
@@ -177,9 +165,7 @@ def main(argv=None) -> int:
     phases = ([("four_chips", phase_four_chips)] if args.four_chips else
               [("polish", phase_polish), ("circulant", phase_circulant)])
     for name, phase in phases:
-        out, secs = _timed(phase)
-        print(f"phase {name}: ok in {secs:.1f} s {json.dumps(out)}",
-              flush=True)
+        print(f"phase {name}: ok {json.dumps(phase())}", flush=True)
     print(json.dumps({"ok": True, "device": device}))
     return 0
 

@@ -13,6 +13,8 @@ from collections.abc import Iterable
 
 import numpy as np
 
+from ... import obs
+
 _CACHE: dict = {}
 CHUNK = 32  # candidates per jitted call (padded, so shapes stay static)
 
@@ -100,12 +102,13 @@ def profile_batch(n: int, offset_lists, engine: str,
         # acceptance never pays for the unexamined chunks (mirrors the
         # numpy generator)
         for lo in range(0, len(shifts), CHUNK):
-            chunk = arr[lo : lo + CHUNK]
-            real = len(chunk)
-            if real < CHUNK:
-                chunk = np.concatenate(
-                    [chunk, np.repeat(chunk[:1], CHUNK - real, axis=0)])
-            total, diam, conn = (np.asarray(x) for x in sweep(chunk))
+            with obs.span("repro.hillclimb.chunk"):
+                chunk = arr[lo : lo + CHUNK]
+                real = len(chunk)
+                if real < CHUNK:
+                    chunk = np.concatenate(
+                        [chunk, np.repeat(chunk[:1], CHUNK - real, axis=0)])
+                total, diam, conn = (np.asarray(x) for x in sweep(chunk))
             for i in range(real):
                 if conn[i]:
                     yield (int(total[i]) / (n - 1), float(diam[i]))
@@ -113,3 +116,13 @@ def profile_batch(n: int, offset_lists, engine: str,
                     yield (float("inf"), float("inf"))
 
     return chunks()
+
+
+def rows_priced(engine: str, consumed: int) -> int:
+    """Rows ``profile_batch`` priced for a caller that took the first
+    ``consumed`` values of one batch: whole ``CHUNK``-row chunks, padding
+    included, on the jax engine (chunks are priced as they are reached);
+    one per value on the lazy numpy path."""
+    if engine != "jax" or jax_modules()[0] is None:
+        return consumed
+    return -(-consumed // CHUNK) * CHUNK

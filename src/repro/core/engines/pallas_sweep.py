@@ -22,6 +22,7 @@ import os
 
 import numpy as np
 
+from ... import obs
 from .base import Engine
 
 # None = unresolved: the first get_interpret() call resolves it from the
@@ -278,10 +279,16 @@ def sharded_delta_state(
             f"sentinel={sentinel})")
     # src doubles as the merge scatter index: lane j of proposal i re-sweeps
     # representative row src[i, j]
-    nb, src = bfs_sweep.pack_sweep(nbrs, sources_list)
-    patch = bfs_sweep.pack_patch(patches, s)
+    with obs.span("repro.dispatch.pack"):
+        nb, src = bfs_sweep.pack_sweep(nbrs, sources_list)
+        patch = bfs_sweep.pack_patch(patches, s)
+        base = np.ascontiguousarray(base)
     mmax, amax = patch[2].shape[1], patch[4].shape[1]
-    rowsums, mx, state = _sharded_delta_fn(
-        r, b // r, n, kmax, s, src.shape[1], mmax, amax, sentinel,
-        use_pallas)(np.ascontiguousarray(base), nb, src, *patch)
-    return np.asarray(rowsums).sum(1, dtype=np.int64), np.asarray(mx), state
+    # the call returns once the inputs are enqueued; the pull of the totals
+    # waits for the upload and the whole program
+    with obs.span("repro.dispatch.run"):
+        rowsums, mx, state = _sharded_delta_fn(
+            r, b // r, n, kmax, s, src.shape[1], mmax, amax, sentinel,
+            use_pallas)(base, nb, src, *patch)
+        return (np.asarray(rowsums).sum(1, dtype=np.int64), np.asarray(mx),
+                state)

@@ -48,6 +48,7 @@ from collections.abc import Iterable
 
 import numpy as np
 
+from .. import obs
 from . import engines, metrics
 from .graphs import Graph, circulant, from_edges, random_hamiltonian_regular, ring
 
@@ -597,82 +598,96 @@ def circulant_search(
             return float("inf"), float("inf")
         return _circulant_profile(n, offs)
 
-    n_free = half - (1 if include_ring else 0)
-    lo, hi = 2, n // 2 - (1 if has_anti else 0)
-    pool = list(range(lo, hi))
-    if n_free > len(pool):
-        raise ValueError(f"degree {k} too large for circulant on {n} vertices")
-    best_offs: list[int] | None = None
-    best = (float("inf"), float("inf"))
-    history: list[float] = []
-    it = 0
-    restarts = max(1, n_iter // 50)
-    for _ in range(restarts):
-        offs = sorted(rng.choice(pool, size=n_free, replace=False).tolist()) if n_free else []
-        cur = mpl_of(offs)
-        improved = True
-        while improved and it < n_iter:
-            improved = False
-            for pos in range(len(offs)):
-                # exhaustive sweep of the position when affordable, else a
-                # random subsample (the paper's large-space regime)
-                cands = pool if len(pool) * len(offs) <= n_iter else \
-                    rng.permutation(pool)[: min(32, len(pool))]
-                cands = [int(c) for c in cands]
-                # price the unexamined tail against the current offsets in
-                # one batch; an acceptance mid-sweep restarts the tail
-                # against the new base — exactly the sequential semantics,
-                # so numpy and jax pricing follow the same trajectory
-                i = 0
-                while i < len(cands):
-                    tail = cands[i:]
-                    # one eligibility pass drives both the batch and its
-                    # consumption, so the vals iterator cannot desync:
-                    # trials[j] is None for skipped candidates (already in
-                    # offs, or duplicate full offsets — inf, never accepted)
-                    trials = []
-                    for c in tail:
-                        t = None if c in offs else \
-                            sorted(offs[:pos] + [c] + offs[pos + 1 :])
-                        if t is not None:
-                            fo = full_offsets(t)
-                            if len(set(fo)) != len(fo):
-                                t = None
-                        trials.append(t)
-                    vals = iter(_profile_batch(
-                        n, [full_offsets(t) for t in trials if t is not None],
-                        engine))
-                    adv = len(tail)
-                    for j, trial in enumerate(trials):
-                        it += 1
-                        if trial is None:
-                            continue
-                        val = next(vals)
-                        if val < cur:
-                            offs, cur = trial, val
-                            improved = True
-                            adv = j + 1
-                            break
-                    i += adv
+    with obs.span("repro.hillclimb"):
+        n_free = half - (1 if include_ring else 0)
+        lo, hi = 2, n // 2 - (1 if has_anti else 0)
+        pool = list(range(lo, hi))
+        if n_free > len(pool):
+            raise ValueError(f"degree {k} too large for circulant on {n} vertices")
+        best_offs: list[int] | None = None
+        best = (float("inf"), float("inf"))
+        history: list[float] = []
+        it = 0
+        # values the accept loop consumed, and rows the pricer priced for them
+        examined = priced_rows = 0
+        restarts = max(1, n_iter // 50)
+        for _ in range(restarts):
+            with obs.span("repro.hillclimb.start"):
+                offs = sorted(rng.choice(pool, size=n_free, replace=False).tolist()) if n_free else []
+                cur = mpl_of(offs)
+            improved = True
+            while improved and it < n_iter:
+                improved = False
+                for pos in range(len(offs)):
+                    # exhaustive sweep of the position when affordable, else a
+                    # random subsample (the paper's large-space regime)
+                    with obs.span("repro.hillclimb.propose"):
+                        cands = pool if len(pool) * len(offs) <= n_iter else \
+                            rng.permutation(pool)[: min(32, len(pool))]
+                        cands = [int(c) for c in cands]
+                    # price the unexamined tail against the current offsets in
+                    # one batch; an acceptance mid-sweep restarts the tail
+                    # against the new base — exactly the sequential semantics,
+                    # so numpy and jax pricing follow the same trajectory
+                    i = 0
+                    while i < len(cands):
+                        tail = cands[i:]
+                        # one eligibility pass drives both the batch and its
+                        # consumption, so the vals iterator cannot desync:
+                        # trials[j] is None for skipped candidates (already in
+                        # offs, or duplicate full offsets — inf, never accepted)
+                        with obs.span("repro.hillclimb.propose"):
+                            trials = []
+                            for c in tail:
+                                t = None if c in offs else \
+                                    sorted(offs[:pos] + [c] + offs[pos + 1 :])
+                                if t is not None:
+                                    fo = full_offsets(t)
+                                    if len(set(fo)) != len(fo):
+                                        t = None
+                                trials.append(t)
+                            vals = iter(_profile_batch(
+                                n, [full_offsets(t) for t in trials if t is not None],
+                                engine))
+                        adv = len(tail)
+                        used = 0
+                        for j, trial in enumerate(trials):
+                            it += 1
+                            if trial is None:
+                                continue
+                            val = next(vals)
+                            used += 1
+                            if val < cur:
+                                offs, cur = trial, val
+                                improved = True
+                                adv = j + 1
+                                break
+                        i += adv
+                        examined += used
+                        priced_rows += engines.jax_circulant.rows_priced(
+                            engine, used)
+                if cur < best:
+                    best, best_offs = cur, list(offs)
+                    history.append(best[0])
             if cur < best:
                 best, best_offs = cur, list(offs)
                 history.append(best[0])
-        if cur < best:
-            best, best_offs = cur, list(offs)
-            history.append(best[0])
-    offs = full_offsets(best_offs or [])
-    g = circulant(n, offs, f"({n},{k})-Suboptimal")
-    return SearchResult(
-        graph=g,
-        mpl=best[0],
-        diameter=best[1],
-        mpl_lb=metrics.mpl_lower_bound(n, k),
-        d_lb=metrics.diameter_lower_bound(n, k),
-        iterations=it,
-        accepted=it,
-        history=history,
-        offsets=tuple(offs),
-    )
+        obs.mark("repro.hillclimb.tally", examined=examined,
+                 priced_rows=priced_rows)
+        with obs.span("repro.hillclimb.finish"):
+            offs = full_offsets(best_offs or [])
+            g = circulant(n, offs, f"({n},{k})-Suboptimal")
+        return SearchResult(
+            graph=g,
+            mpl=best[0],
+            diameter=best[1],
+            mpl_lb=metrics.mpl_lower_bound(n, k),
+            d_lb=metrics.diameter_lower_bound(n, k),
+            iterations=it,
+            accepted=it,
+            history=history,
+            offsets=tuple(offs),
+        )
 
 
 # --------------------------------------------------------------------------------
@@ -1044,16 +1059,19 @@ def _resync_check(chains, s: int, n: int, use_pallas: bool) -> None:
     ``AssertionError`` on any divergence."""
     from .engines import pallas_sweep
 
-    base = np.stack([ch.dist for ch in chains])
-    nbrs = np.stack([ch.nbr for ch in chains]).astype(np.int32, copy=False)
-    _, _, state = pallas_sweep.sharded_delta_state(
-        base, nbrs, [np.arange(s)] * len(chains), [None] * len(chains), n,
-        use_pallas=use_pallas)
-    for r, ch in enumerate(chains):
-        if not np.array_equal(np.asarray(state[r]), ch.dist):
-            raise AssertionError(
-                f"delta pricing drift: replica {r} incremental distance "
-                f"state diverged from the full re-sweep")
+    with obs.span("repro.polish.resync"):
+        with obs.span("repro.dispatch.pack"):
+            base = np.stack([ch.dist for ch in chains])
+            nbrs = np.stack([ch.nbr for ch in chains]).astype(np.int32,
+                                                              copy=False)
+        _, _, state = pallas_sweep.sharded_delta_state(
+            base, nbrs, [np.arange(s)] * len(chains), [None] * len(chains), n,
+            use_pallas=use_pallas)
+        for r, ch in enumerate(chains):
+            if not np.array_equal(np.asarray(state[r]), ch.dist):
+                raise AssertionError(
+                    f"delta pricing drift: replica {r} incremental distance "
+                    f"state diverged from the full re-sweep")
 
 
 def _replica_polish(
@@ -1110,180 +1128,188 @@ def _replica_polish(
 
     if proposal_batch < 1:
         raise ValueError(f"proposal_batch must be >= 1, got {proposal_batch}")
-    use_pallas = engines.resolve_rows(engine).device_sweep
-    s = n // fold
-    gamma = math.exp(math.log(t_end / t_start) / n_iter)
-    ring_edges = {(i, (i + 1) % n) for i in range(n - 1)} | {(0, n - 1)}
+    with obs.span("repro.polish", iterations=n_iter):
+        use_pallas = engines.resolve_rows(engine).device_sweep
+        with obs.span("repro.polish.setup"):
+            s = n // fold
+            gamma = math.exp(math.log(t_end / t_start) / n_iter)
+            ring_edges = {(i, (i + 1) % n) for i in range(n - 1)} | {(0, n - 1)}
 
-    def adj_of(orbs) -> np.ndarray:
-        a = np.zeros((n, n), dtype=bool)
-        for i, j in ring_edges:
-            a[i, j] = a[j, i] = True
-        for orb in orbs:
-            for i, j in orb:
-                a[i, j] = a[j, i] = True
-        return a
+            def adj_of(orbs) -> np.ndarray:
+                a = np.zeros((n, n), dtype=bool)
+                for i, j in ring_edges:
+                    a[i, j] = a[j, i] = True
+                for orb in orbs:
+                    for i, j in orb:
+                        a[i, j] = a[j, i] = True
+                return a
 
-    start = sorted(start_orbits, key=sorted)
-    chains = [_PolishChain(np.random.default_rng([seed, r]), start,
-                           adj_of(start), t_start)
-              for r in range(replicas)]
-    norm = s * (n - 1)
-    dispatches = 1
-    # all chains share the warm start: one stacked pricing seeds cur/best
-    if delta:
-        tot0, mx0, st0 = pallas_sweep.sharded_delta_state(
-            np.zeros((1, s, n), dtype=np.int32), np.stack([chains[0].nbr]),
-            [np.arange(s)], [None], n, use_pallas=use_pallas)
-        dist0 = np.asarray(st0[0])
-        for ch in chains:
-            ch.dist, ch.best_dist = dist0, dist0
-    else:
-        tot0, mx0 = pallas_sweep.sharded_rows_totals(
-            np.stack([chains[0].nbr]), s, n, use_pallas=use_pallas)
-    mpl0 = tot0[0] / norm if mx0[0] < n else float("inf")
-    d0 = float(mx0[0]) if mx0[0] < n else float("inf")
-    for ch in chains:
-        ch.cur_mpl = ch.best_mpl = mpl0
-        ch.cur_d = ch.best_d = d0
-
-    mprop = proposal_batch
-    bsz = replicas * mprop
-    accepted = 0
-    evals_delta = evals_full = 0
-    history = [mpl0]
-    global_best = (mpl0, d0)
-    nbr_stack = np.empty((bsz,) + chains[0].nbr.shape, dtype=np.int32)
-    empty = np.empty(0, dtype=np.int64)
-    for it in range(n_iter):
-        proposals: list = [None] * bsz
-        srcs: list = [empty] * bsz
-        patches: list = [None] * bsz
-        for r, ch in enumerate(chains):
-            ch.t *= gamma
-            for m in range(mprop):
-                slot = r * mprop + m
-                nbr_stack[slot] = ch.nbr  # idle slots price the unchanged graph
-                if len(ch.orb_list) < 2:
-                    continue
-                mv = _draw_orbit_swap(ch.rng, ch.orb_list, ch.chord_edges,
-                                      ring_edges, n, s, fold)
-                if mv is None:
-                    continue
-                i1, i2, no1, no2, new_edges, remaining = mv
-                work_list = [o for idx, o in enumerate(ch.orb_list)
-                             if idx not in (i1, i2)] + [no1, no2]
-                work_chords = remaining | new_edges
-                removed = sorted(ch.chord_edges - work_chords)
-                added = sorted(work_chords - ch.chord_edges)
-                if delta:
-                    aff = metrics._removal_affected_nbr(ch.dist, ch.nbr,
-                                                        removed)
-                    full = (ch.cur_d == float("inf")
-                            or int(aff.sum()) > full_rebuild_frac * s)
-                    if full:
-                        nbr_stack[slot] = ch.trial_nbr(removed, added)
-                        srcs[slot] = np.arange(s)
-                        evals_full += 1
-                    else:
-                        # re-sweep only the affected rows on the post-removal
-                        # graph; the added edges come back as a min-plus patch
-                        nbr_stack[slot] = ch.trial_nbr(removed, ())
-                        srcs[slot] = np.nonzero(aff)[0]
-                        patches[slot] = added
-                        evals_delta += 1
-                    proposals[slot] = (removed, added, work_list, work_chords,
-                                       None)
-                else:
-                    nbr_stack[slot] = tn = ch.trial_nbr(removed, added)
-                    evals_full += 1
-                    proposals[slot] = (removed, added, work_list, work_chords,
-                                       tn)
-        if any(p is not None for p in proposals):
+            start = sorted(start_orbits, key=sorted)
+            chains = [_PolishChain(np.random.default_rng([seed, r]), start,
+                                   adj_of(start), t_start)
+                      for r in range(replicas)]
+            norm = s * (n - 1)
+            dispatches = 1
+            # all chains share the warm start: one stacked pricing seeds cur/best
             if delta:
-                totals, maxima, states = pallas_sweep.sharded_delta_state(
-                    np.stack([ch.dist for ch in chains]), nbr_stack, srcs,
-                    patches, n, use_pallas=use_pallas)
+                tot0, mx0, st0 = pallas_sweep.sharded_delta_state(
+                    np.zeros((1, s, n), dtype=np.int32), np.stack([chains[0].nbr]),
+                    [np.arange(s)], [None], n, use_pallas=use_pallas)
+                dist0 = np.asarray(st0[0])
+                for ch in chains:
+                    ch.dist, ch.best_dist = dist0, dist0
             else:
-                totals, maxima = pallas_sweep.sharded_rows_totals(
-                    nbr_stack, s, n, use_pallas=use_pallas)
-                states = None
-            dispatches += 1
-            state_np = None  # whole-batch device->host pull, once per dispatch
-            for r, ch in enumerate(chains):
-                committed = False
-                for m in range(mprop):
-                    slot = r * mprop + m
-                    if proposals[slot] is None or committed:
-                        continue  # discarded batch slots consume no RNG
-                    new_mpl = (totals[slot] / norm if maxima[slot] < n
-                               else float("inf"))
-                    new_d = (float(maxima[slot]) if maxima[slot] < n
-                             else float("inf"))
-                    dm = new_mpl - ch.cur_mpl
-                    if not (dm < 0
-                            or ch.rng.random() < math.exp(-dm / max(ch.t, 1e-12))):
-                        continue
-                    removed, added, work_list, work_chords, tn = proposals[slot]
-                    if tn is None:  # delta slots carry the post-removal table
-                        tn = ch.trial_nbr(removed, added)
-                    ch.commit(removed, added, work_list, work_chords, tn,
-                              new_mpl, new_d)
-                    if delta:
-                        if state_np is None:
-                            state_np = np.asarray(states)
-                        ch.dist = state_np[slot]
-                    committed = True
-                    accepted += 1
-                    if (ch.cur_mpl, ch.cur_d) < (ch.best_mpl, ch.best_d):
-                        ch.best_orbits = set(ch.orb_list)
-                        ch.best_mpl, ch.best_d = ch.cur_mpl, ch.cur_d
-                        if delta:
-                            ch.best_dist = ch.dist
-                        if (ch.best_mpl, ch.best_d) < global_best:
-                            global_best = (ch.best_mpl, ch.best_d)
-                            history.append(ch.best_mpl)
-            if replicas > 1 and (it + 1) % exchange_every == 0 and it + 1 < n_iter:
-                gb = min(range(replicas),
-                         key=lambda r: (chains[r].best_mpl, chains[r].best_d, r))
-                worst = max(range(1, replicas),
-                            key=lambda r: (chains[r].cur_mpl, chains[r].cur_d, -r))
-                if (chains[gb].best_mpl, chains[gb].best_d) < \
-                        (chains[worst].cur_mpl, chains[worst].cur_d):
-                    ch = chains[worst]
-                    ch.orb_list = sorted(chains[gb].best_orbits, key=sorted)
-                    ch.chord_edges = {e for orb in ch.orb_list for e in orb}
-                    ch.adj = adj_of(ch.orb_list)
-                    ch.nbr = metrics._nbr_table(ch.adj)
-                    ch.cur_mpl, ch.cur_d = chains[gb].best_mpl, chains[gb].best_d
-                    if delta:
-                        ch.dist = chains[gb].best_dist
-        if delta and (it + 1 == n_iter
-                      or (resync_every and (it + 1) % resync_every == 0)):
-            _resync_check(chains, s, n, use_pallas)
-            dispatches += 1
+                tot0, mx0 = pallas_sweep.sharded_rows_totals(
+                    np.stack([chains[0].nbr]), s, n, use_pallas=use_pallas)
+            mpl0 = tot0[0] / norm if mx0[0] < n else float("inf")
+            d0 = float(mx0[0]) if mx0[0] < n else float("inf")
+            for ch in chains:
+                ch.cur_mpl = ch.best_mpl = mpl0
+                ch.cur_d = ch.best_d = d0
 
-    gb = min(range(replicas),
-             key=lambda r: (chains[r].best_mpl, chains[r].best_d, r))
-    best = chains[gb]
-    edges = set(ring_edges)
-    for orb in best.best_orbits:
-        edges |= set(orb)
-    g = from_edges(n, edges, f"({n},{k})-Suboptimal")
-    return SearchResult(
-        graph=g,
-        mpl=best.best_mpl,
-        diameter=best.best_d,
-        mpl_lb=metrics.mpl_lower_bound(n, k),
-        d_lb=metrics.diameter_lower_bound(n, k),
-        iterations=n_iter,
-        accepted=accepted,
-        history=history,
-        replicas=replicas,
-        evals_delta=evals_delta,
-        evals_full=evals_full,
-        device_dispatches=dispatches,
-    )
+            mprop = proposal_batch
+            bsz = replicas * mprop
+            accepted = 0
+            evals_delta = evals_full = 0
+            history = [mpl0]
+            global_best = (mpl0, d0)
+            nbr_stack = np.empty((bsz,) + chains[0].nbr.shape, dtype=np.int32)
+            empty = np.empty(0, dtype=np.int64)
+        for it in range(n_iter):
+            proposals: list = [None] * bsz
+            srcs: list = [empty] * bsz
+            patches: list = [None] * bsz
+            with obs.span("repro.polish.propose"):
+                for r, ch in enumerate(chains):
+                    ch.t *= gamma
+                    for m in range(mprop):
+                        slot = r * mprop + m
+                        nbr_stack[slot] = ch.nbr  # idle slots price the unchanged graph
+                        if len(ch.orb_list) < 2:
+                            continue
+                        mv = _draw_orbit_swap(ch.rng, ch.orb_list, ch.chord_edges,
+                                              ring_edges, n, s, fold)
+                        if mv is None:
+                            continue
+                        i1, i2, no1, no2, new_edges, remaining = mv
+                        work_list = [o for idx, o in enumerate(ch.orb_list)
+                                     if idx not in (i1, i2)] + [no1, no2]
+                        work_chords = remaining | new_edges
+                        removed = sorted(ch.chord_edges - work_chords)
+                        added = sorted(work_chords - ch.chord_edges)
+                        if delta:
+                            aff = metrics._removal_affected_nbr(ch.dist, ch.nbr,
+                                                                removed)
+                            full = (ch.cur_d == float("inf")
+                                    or int(aff.sum()) > full_rebuild_frac * s)
+                            if full:
+                                nbr_stack[slot] = ch.trial_nbr(removed, added)
+                                srcs[slot] = np.arange(s)
+                                evals_full += 1
+                            else:
+                                # re-sweep only the affected rows on the post-removal
+                                # graph; the added edges come back as a min-plus patch
+                                nbr_stack[slot] = ch.trial_nbr(removed, ())
+                                srcs[slot] = np.nonzero(aff)[0]
+                                patches[slot] = added
+                                evals_delta += 1
+                            proposals[slot] = (removed, added, work_list, work_chords,
+                                               None)
+                        else:
+                            nbr_stack[slot] = tn = ch.trial_nbr(removed, added)
+                            evals_full += 1
+                            proposals[slot] = (removed, added, work_list, work_chords,
+                                               tn)
+            if any(p is not None for p in proposals):
+                if delta:
+                    with obs.span("repro.dispatch.pack"):
+                        base = np.stack([ch.dist for ch in chains])
+                    totals, maxima, states = pallas_sweep.sharded_delta_state(
+                        base, nbr_stack, srcs, patches, n, use_pallas=use_pallas)
+                else:
+                    totals, maxima = pallas_sweep.sharded_rows_totals(
+                        nbr_stack, s, n, use_pallas=use_pallas)
+                    states = None
+                dispatches += 1
+                with obs.span("repro.polish.accept"):
+                    state_np = None  # whole-batch device->host pull, once per dispatch
+                    for r, ch in enumerate(chains):
+                        committed = False
+                        for m in range(mprop):
+                            slot = r * mprop + m
+                            if proposals[slot] is None or committed:
+                                continue  # discarded batch slots consume no RNG
+                            new_mpl = (totals[slot] / norm if maxima[slot] < n
+                                       else float("inf"))
+                            new_d = (float(maxima[slot]) if maxima[slot] < n
+                                     else float("inf"))
+                            dm = new_mpl - ch.cur_mpl
+                            if not (dm < 0
+                                    or ch.rng.random() < math.exp(-dm / max(ch.t, 1e-12))):
+                                continue
+                            removed, added, work_list, work_chords, tn = proposals[slot]
+                            if tn is None:  # delta slots carry the post-removal table
+                                tn = ch.trial_nbr(removed, added)
+                            ch.commit(removed, added, work_list, work_chords, tn,
+                                      new_mpl, new_d)
+                            if delta:
+                                if state_np is None:
+                                    with obs.span("repro.polish.pull"):
+                                        state_np = np.asarray(states)
+                                ch.dist = state_np[slot]
+                            committed = True
+                            accepted += 1
+                            if (ch.cur_mpl, ch.cur_d) < (ch.best_mpl, ch.best_d):
+                                ch.best_orbits = set(ch.orb_list)
+                                ch.best_mpl, ch.best_d = ch.cur_mpl, ch.cur_d
+                                if delta:
+                                    ch.best_dist = ch.dist
+                                if (ch.best_mpl, ch.best_d) < global_best:
+                                    global_best = (ch.best_mpl, ch.best_d)
+                                    history.append(ch.best_mpl)
+                if replicas > 1 and (it + 1) % exchange_every == 0 and it + 1 < n_iter:
+                    with obs.span("repro.polish.exchange"):
+                        gb = min(range(replicas),
+                                 key=lambda r: (chains[r].best_mpl, chains[r].best_d, r))
+                        worst = max(range(1, replicas),
+                                    key=lambda r: (chains[r].cur_mpl, chains[r].cur_d, -r))
+                        if (chains[gb].best_mpl, chains[gb].best_d) < \
+                                (chains[worst].cur_mpl, chains[worst].cur_d):
+                            ch = chains[worst]
+                            ch.orb_list = sorted(chains[gb].best_orbits, key=sorted)
+                            ch.chord_edges = {e for orb in ch.orb_list for e in orb}
+                            ch.adj = adj_of(ch.orb_list)
+                            ch.nbr = metrics._nbr_table(ch.adj)
+                            ch.cur_mpl, ch.cur_d = chains[gb].best_mpl, chains[gb].best_d
+                            if delta:
+                                ch.dist = chains[gb].best_dist
+            if delta and (it + 1 == n_iter
+                          or (resync_every and (it + 1) % resync_every == 0)):
+                _resync_check(chains, s, n, use_pallas)
+                dispatches += 1
+
+        with obs.span("repro.polish.finish"):
+            gb = min(range(replicas),
+                     key=lambda r: (chains[r].best_mpl, chains[r].best_d, r))
+            best = chains[gb]
+            edges = set(ring_edges)
+            for orb in best.best_orbits:
+                edges |= set(orb)
+            g = from_edges(n, edges, f"({n},{k})-Suboptimal")
+        return SearchResult(
+            graph=g,
+            mpl=best.best_mpl,
+            diameter=best.best_d,
+            mpl_lb=metrics.mpl_lower_bound(n, k),
+            d_lb=metrics.diameter_lower_bound(n, k),
+            iterations=n_iter,
+            accepted=accepted,
+            history=history,
+            replicas=replicas,
+            evals_delta=evals_delta,
+            evals_full=evals_full,
+            device_dispatches=dispatches,
+        )
 
 
 # --------------------------------------------------------------------------------
