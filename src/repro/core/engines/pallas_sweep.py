@@ -12,7 +12,9 @@ unless ``REPRO_PALLAS_INTERPRET`` says otherwise.  The model-zoo kernels in
 ``sharded_rows_totals`` and ``sharded_delta_state`` are the replica-polish
 entry points: R stacked neighbour tables are priced in one ``shard_map``
 over the replica axis, so each device sweeps its replicas' graphs locally
-and only per-replica scalars (and, for the delta path, the state) come home.
+and only per-replica scalars come home.  The delta path's distance state
+stays on the devices: ``stack_states``, ``take_slot``, ``state_columns``
+and ``states_equal`` stack, select, probe and compare it there.
 """
 from __future__ import annotations
 
@@ -115,6 +117,14 @@ def _mesh(r: int):
     devs = _DEVICES or tuple(_jax().devices())
     nd = max(d for d in range(1, min(r, len(devs)) + 1) if r % d == 0)
     return Mesh(np.asarray(devs[:nd]), ("r",))
+
+
+def _sharding(r: int, *spec):
+    """``NamedSharding`` over ``_mesh(r)``: ``("r",)`` splits the leading
+    replica axis, no spec replicates."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    return NamedSharding(_mesh(r), P(*spec))
 
 
 def _sweep(use_pallas: bool, interpret: bool, n: int, kmax: int, lanes: int,
@@ -262,10 +272,13 @@ def sharded_delta_state(
     all rows affected, post-swap table, no patch.  Proposal i belongs to
     chain ``i // M`` (replica-major order, M = b // R proposals per chain).
 
-    Returns ``(totals (b,) int64, maxima (b,) int32, state)`` where state is
-    the (b, s, n) post-swap representative rows (a device array; callers
-    slice the accepted proposals).  Exact integer hop counts: bit-identical
-    to the full sweep, per the property tests.
+    ``base`` may be a host array or a device array (``stack_states``); a
+    device base is not copied back.  Returns ``(totals (b,) int64, maxima
+    (b,) int32, state)`` where state is the (b, s, n) post-swap
+    representative rows, a device array sharded over the replica axis
+    (callers select the accepted proposals with ``take_slot``).  Exact
+    integer hop counts: bit-identical to the full sweep, per the property
+    tests.
     """
     from ...kernels import bfs_sweep
 
@@ -282,13 +295,86 @@ def sharded_delta_state(
     with obs.span("repro.dispatch.pack"):
         nb, src = bfs_sweep.pack_sweep(nbrs, sources_list)
         patch = bfs_sweep.pack_patch(patches, s)
-        base = np.ascontiguousarray(base)
     mmax, amax = patch[2].shape[1], patch[4].shape[1]
     # the call returns once the inputs are enqueued; the pull of the totals
-    # waits for the upload and the whole program
+    # waits for the upload and the whole program.  A host base is uploaded
+    # and a device base left where it is, with one placement either way, so
+    # both reach the same compiled program
     with obs.span("repro.dispatch.run"):
+        base = _jax().device_put(base, _sharding(r, "r"))
         rowsums, mx, state = _sharded_delta_fn(
             r, b // r, n, kmax, s, src.shape[1], mmax, amax, sentinel,
             use_pallas)(base, nb, src, *patch)
         return (np.asarray(rowsums).sum(1, dtype=np.int64), np.asarray(mx),
                 state)
+
+
+# ------------------------------------------------------------------------------
+# Device-resident chain state (the delta polish's distance rows)
+# ------------------------------------------------------------------------------
+
+def _helper(fn, **kw):
+    """``jax.jit(fn)``, cached under ``fn``'s name: the program is named
+    after it (never ``per_shard``, the dispatch's own name)."""
+    key = ("helper", fn.__name__, tuple(sorted(kw.items())))
+    out = _CACHE.get(key)
+    if out is None:
+        out = _CACHE[key] = _jax().jit(fn, **kw)
+    return out
+
+
+def stack_states(states, replicas: int):
+    """Stack R (s, n) chain states into the (R, s, n) dispatch base on the
+    device, sharded over the replica axis.  Device states are left where
+    they are (host ones, as tests pass, are uploaded); nothing is pulled."""
+    import jax.numpy as jnp
+
+    def stack_states(xs):
+        return jnp.stack(xs)
+
+    sharding = _sharding(replicas, "r")
+    xs = _jax().device_put(list(states), _sharding(replicas))
+    return _helper(stack_states, out_shardings=sharding)(xs)
+
+
+def take_slot(states, slot: int, replicas: int):
+    """Proposal ``slot``'s (s, n) rows of a (b, s, n) device state, kept on
+    the device and replicated over the replica mesh.  The slot is a traced
+    argument, so one program serves every slot."""
+    from jax import lax
+
+    def take_slot(st, i):
+        return lax.dynamic_index_in_dim(st, i, keepdims=False)
+
+    row = _helper(take_slot)(states, np.int32(slot))
+    return _jax().device_put(row, _sharding(replicas))
+
+
+def state_columns(base, cols: np.ndarray) -> np.ndarray:
+    """Gather columns of the chains' device state and pull them once.
+
+    ``base`` is the (R, s, n) device state, ``cols`` (b, C) int32 vertex
+    columns with proposal i reading chain ``i // (b // R)`` (replica-major,
+    as the dispatch).  Returns the (b, s, C) int32 host array
+    ``out[i] = base[i // M][:, cols[i]]``."""
+    jax = _jax()
+
+    def state_columns(st, cc):
+        r, s, _ = st.shape
+        b, c = cc.shape
+        m = b // r
+        g = jax.vmap(lambda x, ci: x[:, ci])(st, cc.reshape(r, m * c))
+        return g.reshape(r, s, m, c).transpose(0, 2, 1, 3).reshape(b, s, c)
+
+    return np.asarray(_helper(state_columns)(base, cols))
+
+
+def states_equal(a, b) -> np.ndarray:
+    """(R,) bool: whether each replica's (s, n) rows of two (R, s, n)
+    device states agree bit for bit; only the flags leave the device."""
+    import jax.numpy as jnp
+
+    def states_equal(x, y):
+        return jnp.all(x == y, axis=(1, 2))
+
+    return np.asarray(_helper(states_equal)(a, b))

@@ -257,6 +257,29 @@ def _removal_affected_nbr(dist: np.ndarray, nbr: np.ndarray, removed) -> np.ndar
     return aff
 
 
+def _removal_columns(nbr: np.ndarray, removed, width: int):
+    """The columns ``_removal_affected_nbr`` reads, for a compact gather.
+
+    The test reads ``dist`` only at the removed edges' endpoints and at
+    their neighbours.  Returns ``(cols, nbr_c, removed_c)``: ``cols``
+    (width,) int32 lists the P endpoints, then each endpoint's kmax
+    neighbour slots (a pad slot reads column 0), then zeros; ``nbr_c`` and
+    ``removed_c`` are the endpoints' neighbour rows and the removed edges
+    in positions of ``cols``.  So ``_removal_affected_nbr(dist[:, cols],
+    nbr_c, removed_c)`` equals ``_removal_affected_nbr(dist, nbr,
+    removed)``: the endpoints keep their order, and pads stay -1."""
+    pts = sorted({x for e in removed for x in e})
+    p, kmax = len(pts), nbr.shape[1]
+    nb = nbr[pts]
+    cols = np.zeros(width, dtype=np.int32)
+    cols[:p] = pts
+    cols[p:p * (1 + kmax)] = np.where(nb >= 0, nb, 0).ravel()
+    nbr_c = np.where(nb >= 0, p + np.arange(p * kmax).reshape(p, kmax),
+                     -1).astype(np.int32)
+    idx = {x: i for i, x in enumerate(pts)}
+    return cols, nbr_c, [(idx[a], idx[b]) for a, b in removed]
+
+
 @dataclasses.dataclass
 class SwapToken:
     """Pending result of ``IncrementalAPSP.evaluate_swap`` (commit to apply)."""

@@ -1000,11 +1000,11 @@ def symmetric_sa_search(
 class _PolishChain:
     """One replica of the device-priced orbit polish: host-side orbit state
     plus the padded neighbour table the device sweep prices from.  Under
-    delta pricing the chain also mirrors its representative-row distance
-    state (``dist``) — the batched lost-parent removal test gathers parent
-    counts from it on demand — plus the ``best_dist`` snapshot replica
-    exchange restores from.  The mirrors are rebound, never mutated in
-    place, so snapshots are safe by reference."""
+    delta pricing the chain also holds its representative-row distance
+    state (``dist``, a (s, n) int32 device array) and the ``best_dist``
+    snapshot replica exchange restores from; the lost-parent test reads
+    only the columns it needs, gathered on the device.  Both are rebound,
+    never mutated in place, so snapshots are safe by reference."""
 
     __slots__ = ("rng", "orb_list", "chord_edges", "adj", "nbr",
                  "cur_mpl", "cur_d", "best_orbits", "best_mpl", "best_d", "t",
@@ -1055,20 +1055,20 @@ class _PolishChain:
 def _resync_check(chains, s: int, n: int, use_pallas: bool) -> None:
     """Drift guard for the delta-priced polish: re-sweep every chain's
     current graph from scratch in one dispatch and assert the maintained
-    incremental distance state matches bit-for-bit.  Raises
-    ``AssertionError`` on any divergence."""
+    incremental distance state matches bit-for-bit.  The comparison runs
+    where the state lives (host states are uploaded); one flag per replica
+    comes back.  Raises ``AssertionError`` on any divergence."""
     from .engines import pallas_sweep
 
     with obs.span("repro.polish.resync"):
-        with obs.span("repro.dispatch.pack"):
-            base = np.stack([ch.dist for ch in chains])
-            nbrs = np.stack([ch.nbr for ch in chains]).astype(np.int32,
-                                                              copy=False)
+        base = pallas_sweep.stack_states([ch.dist for ch in chains],
+                                         len(chains))
+        nbrs = np.stack([ch.nbr for ch in chains]).astype(np.int32, copy=False)
         _, _, state = pallas_sweep.sharded_delta_state(
             base, nbrs, [np.arange(s)] * len(chains), [None] * len(chains), n,
             use_pallas=use_pallas)
-        for r, ch in enumerate(chains):
-            if not np.array_equal(np.asarray(state[r]), ch.dist):
+        for r, same in enumerate(pallas_sweep.states_equal(state, base)):
+            if not same:
                 raise AssertionError(
                     f"delta pricing drift: replica {r} incremental distance "
                     f"state diverged from the full re-sweep")
@@ -1103,12 +1103,16 @@ def _replica_polish(
     and only per-proposal (total, max) scalars come home.
 
     With ``delta=True`` (default) the dispatch is the incremental-APSP twin
-    ``sharded_delta_state``: each chain host-mirrors its representative-row
-    distances, the batched lost-parent test (parent counts gathered on
-    demand at the removed endpoints) marks the rows a removal touches, and
+    ``sharded_delta_state``: each chain's representative-row distances stay
+    on the device.  Once all proposals are drawn, one gather pulls the
+    columns the lost-parent test reads (each removed endpoint and its
+    neighbours) from each proposal's chain state; the test, run on the
+    host on those compact blocks, marks the rows a removal touches, and
     the device re-sweeps only those rows on
     the post-removal graph before min-plus patching the added edges back in
-    — the ``SymmetricAPSP`` algorithm, vectorized over proposals.  Proposals
+    — the ``SymmetricAPSP`` algorithm, vectorized over proposals.  An
+    accepted proposal's post-swap rows are selected on the device; no
+    whole state crosses to the host.  Proposals
     whose affected set exceeds ``full_rebuild_frac`` of the rows (or whose
     base is disconnected) fall back to a full re-sweep expressed in the same
     vocabulary.  Every ``resync_every`` iterations (and at the end) a full
@@ -1123,6 +1127,12 @@ def _replica_polish(
 
     Every ``exchange_every`` iterations the globally best state replaces the
     worst non-protected chain, exactly like ``sa_search``.
+
+    Under a profiler the call records ``repro.polish.tally``: whole chain
+    states handed from host memory to the device inside the iteration loop
+    (``state_host_copies``; the loop pulls none back), and the column
+    gathers (``column_pulls``) with the bytes they pulled
+    (``column_bytes``).
     """
     from .engines import pallas_sweep
 
@@ -1155,7 +1165,7 @@ def _replica_polish(
                 tot0, mx0, st0 = pallas_sweep.sharded_delta_state(
                     np.zeros((1, s, n), dtype=np.int32), np.stack([chains[0].nbr]),
                     [np.arange(s)], [None], n, use_pallas=use_pallas)
-                dist0 = np.asarray(st0[0])
+                dist0 = pallas_sweep.take_slot(st0, 0, replicas)
                 for ch in chains:
                     ch.dist, ch.best_dist = dist0, dist0
             else:
@@ -1175,11 +1185,18 @@ def _replica_polish(
             global_best = (mpl0, d0)
             nbr_stack = np.empty((bsz,) + chains[0].nbr.shape, dtype=np.int32)
             empty = np.empty(0, dtype=np.int64)
+            # the lost-parent test's columns: two swapped orbits remove at
+            # most 2 * fold edges, so 4 * fold endpoints, each with kmax
+            # neighbours; one gather shape per configuration
+            cols = np.zeros((bsz, 4 * fold * (1 + chains[0].nbr.shape[1])),
+                            dtype=np.int32)
+            host_copies = column_pulls = column_bytes = 0
         for it in range(n_iter):
             proposals: list = [None] * bsz
             srcs: list = [empty] * bsz
             patches: list = [None] * bsz
             with obs.span("repro.polish.propose"):
+                drawn = []  # delta proposals, tested once their columns are in
                 for r, ch in enumerate(chains):
                     ch.t *= gamma
                     for m in range(mprop):
@@ -1198,32 +1215,46 @@ def _replica_polish(
                         removed = sorted(ch.chord_edges - work_chords)
                         added = sorted(work_chords - ch.chord_edges)
                         if delta:
-                            aff = metrics._removal_affected_nbr(ch.dist, ch.nbr,
-                                                                removed)
-                            full = (ch.cur_d == float("inf")
-                                    or int(aff.sum()) > full_rebuild_frac * s)
-                            if full:
-                                nbr_stack[slot] = ch.trial_nbr(removed, added)
-                                srcs[slot] = np.arange(s)
-                                evals_full += 1
-                            else:
-                                # re-sweep only the affected rows on the post-removal
-                                # graph; the added edges come back as a min-plus patch
-                                nbr_stack[slot] = ch.trial_nbr(removed, ())
-                                srcs[slot] = np.nonzero(aff)[0]
-                                patches[slot] = added
-                                evals_delta += 1
-                            proposals[slot] = (removed, added, work_list, work_chords,
-                                               None)
+                            drawn.append((slot, ch, removed, added, work_list,
+                                          work_chords))
                         else:
                             nbr_stack[slot] = tn = ch.trial_nbr(removed, added)
                             evals_full += 1
                             proposals[slot] = (removed, added, work_list, work_chords,
                                                tn)
+                if drawn:
+                    with obs.span("repro.polish.columns"):
+                        cols[:] = 0  # idle slots gather column 0, unread
+                        compact = {}
+                        for slot, ch, removed, *_ in drawn:
+                            cols[slot], nbr_c, removed_c = metrics._removal_columns(
+                                ch.nbr, removed, cols.shape[1])
+                            compact[slot] = (nbr_c, removed_c)
+                        host_copies += sum(isinstance(ch.dist, np.ndarray)
+                                           for ch in chains)
+                        base = pallas_sweep.stack_states(
+                            [ch.dist for ch in chains], replicas)
+                        block = pallas_sweep.state_columns(base, cols)
+                        column_pulls += 1
+                        column_bytes += block.nbytes
+                for slot, ch, removed, added, work_list, work_chords in drawn:
+                    aff = metrics._removal_affected_nbr(block[slot], *compact[slot])
+                    full = (ch.cur_d == float("inf")
+                            or int(aff.sum()) > full_rebuild_frac * s)
+                    if full:
+                        nbr_stack[slot] = ch.trial_nbr(removed, added)
+                        srcs[slot] = np.arange(s)
+                        evals_full += 1
+                    else:
+                        # re-sweep only the affected rows on the post-removal
+                        # graph; the added edges come back as a min-plus patch
+                        nbr_stack[slot] = ch.trial_nbr(removed, ())
+                        srcs[slot] = np.nonzero(aff)[0]
+                        patches[slot] = added
+                        evals_delta += 1
+                    proposals[slot] = (removed, added, work_list, work_chords, None)
             if any(p is not None for p in proposals):
                 if delta:
-                    with obs.span("repro.dispatch.pack"):
-                        base = np.stack([ch.dist for ch in chains])
                     totals, maxima, states = pallas_sweep.sharded_delta_state(
                         base, nbr_stack, srcs, patches, n, use_pallas=use_pallas)
                 else:
@@ -1232,7 +1263,6 @@ def _replica_polish(
                     states = None
                 dispatches += 1
                 with obs.span("repro.polish.accept"):
-                    state_np = None  # whole-batch device->host pull, once per dispatch
                     for r, ch in enumerate(chains):
                         committed = False
                         for m in range(mprop):
@@ -1253,10 +1283,8 @@ def _replica_polish(
                             ch.commit(removed, added, work_list, work_chords, tn,
                                       new_mpl, new_d)
                             if delta:
-                                if state_np is None:
-                                    with obs.span("repro.polish.pull"):
-                                        state_np = np.asarray(states)
-                                ch.dist = state_np[slot]
+                                ch.dist = pallas_sweep.take_slot(states, slot,
+                                                                 replicas)
                             committed = True
                             accepted += 1
                             if (ch.cur_mpl, ch.cur_d) < (ch.best_mpl, ch.best_d):
@@ -1285,9 +1313,13 @@ def _replica_polish(
                                 ch.dist = chains[gb].best_dist
             if delta and (it + 1 == n_iter
                           or (resync_every and (it + 1) % resync_every == 0)):
+                host_copies += sum(isinstance(ch.dist, np.ndarray)
+                                   for ch in chains)
                 _resync_check(chains, s, n, use_pallas)
                 dispatches += 1
 
+        obs.mark("repro.polish.tally", state_host_copies=host_copies,
+                 column_pulls=column_pulls, column_bytes=column_bytes)
         with obs.span("repro.polish.finish"):
             gb = min(range(replicas),
                      key=lambda r: (chains[r].best_mpl, chains[r].best_d, r))

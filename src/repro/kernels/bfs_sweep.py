@@ -301,8 +301,9 @@ def bfs_rows(
 # ------------------------------------------------------------------------------
 # Delta sweep: incremental pricing of batched orbit swaps (the device twin of
 # ``metrics.SymmetricAPSP.evaluate_swap``).  The host runs the exact batched
-# lost-parent removal test against its mirrored (dist, npar) state and packs,
-# per proposal, only the *affected* representative rows as sources; the
+# lost-parent removal test on the columns of the state it reads, gathered
+# from the device, and packs, per proposal, only the *affected*
+# representative rows as sources; the
 # sweep then repairs those rows on the post-removal graph, the merged
 # state keeps the provably-unchanged rows, and the min-plus insert patch
 # applies the added edges — exact integer hop counts end to end, so the delta

@@ -99,25 +99,21 @@ def _tiny_polish(**kw):
 def test_replica_polish_stage_spans(trace_dir, monkeypatch):
     """One propose and one accept per iteration, one exchange per
     ``exchange_every``, one resync per ``resync_every`` plus the last, one
-    pull per iteration in which a chain accepted; every stage inside
-    ``repro.polish``; the traced result equals the untraced one."""
+    column gather inside the propose of every iteration with a proposal,
+    one pack per dispatch; every stage inside ``repro.polish``; the traced
+    result equals the untraced one."""
     untraced = _tiny_polish()
 
-    # iterations that accepted, counted apart from the spans: the dispatch
-    # that priced each committed proposal
-    calls = {"dispatch": 0, "accepting": set()}
-    delta, commit = pallas_sweep.sharded_delta_state, search._PolishChain.commit
+    # iterations with a proposal, counted apart from the spans: the
+    # dispatches that price a whole batch (replicas x proposal_batch)
+    calls = {"batches": 0}
+    delta = pallas_sweep.sharded_delta_state
 
-    def delta_w(*a, **kw):
-        calls["dispatch"] += 1
-        return delta(*a, **kw)
-
-    def commit_w(self, *a, **kw):
-        calls["accepting"].add(calls["dispatch"])
-        return commit(self, *a, **kw)
+    def delta_w(base, nbrs, *a, **kw):
+        calls["batches"] += nbrs.shape[0] == 2 * 2
+        return delta(base, nbrs, *a, **kw)
 
     monkeypatch.setattr(pallas_sweep, "sharded_delta_state", delta_w)
-    monkeypatch.setattr(search._PolishChain, "commit", commit_w)
     with jax.profiler.trace(trace_dir):
         traced = _tiny_polish()
     assert traced == untraced
@@ -132,21 +128,54 @@ def test_replica_polish_stage_spans(trace_dir, monkeypatch):
     assert count(ev, "repro.polish.accept") == n_iter
     assert count(ev, "repro.polish.exchange") == 1  # after iteration 5
     assert count(ev, "repro.polish.resync") == resyncs
-    assert calls["accepting"] and 0 < len(calls["accepting"]) <= n_iter
-    assert count(ev, "repro.polish.pull") == len(calls["accepting"])
-    # one run per dispatch; a pack in each, and one in the caller of every
-    # iteration's and every resync's dispatch
+    assert 0 < calls["batches"] <= n_iter
+    assert count(ev, "repro.polish.columns") == calls["batches"]
+    assert count(ev, "repro.polish.pull") == 0
+    # one run and one pack per dispatch: the state is stacked on the device
     assert count(ev, "repro.dispatch.run") == traced.device_dispatches \
-        == 1 + n_iter + resyncs
-    assert count(ev, "repro.dispatch.pack") == \
-        traced.device_dispatches + n_iter + resyncs
+        == 1 + calls["batches"] + resyncs
+    assert count(ev, "repro.dispatch.pack") == traced.device_dispatches
     for name, spans in ev.items():
         assert all(inside(s, polish) for s in spans), name
-    accepts = ev["repro.polish.accept"]
-    assert all(any(inside(p, a) for a in accepts)
-               for p in ev["repro.polish.pull"])
+    proposes = ev["repro.polish.propose"]
+    assert all(any(inside(c, p) for p in proposes)
+               for c in ev["repro.polish.columns"])
     for run in ev["repro.dispatch.run"]:
-        assert not any(inside(run, a) for a in accepts)
+        assert not any(inside(run, p) for p in proposes)
+        assert not any(inside(run, a) for a in ev["repro.polish.accept"])
+
+
+@pytest.mark.parametrize("seed", [0, 4])
+def test_replica_polish_tally(trace_dir, monkeypatch, seed):
+    """At the benchmark cells' shape (4 replicas, 2 proposals each) the
+    chains' state never crosses to the host inside the loop: the tally
+    reads no host copy of a state, one column gather per iteration with a
+    proposal, and that gather's bytes."""
+    n, fold, replicas, mprop, n_iter = 64, 4, 4, 2, 12
+    calls = {"batches": 0}
+    delta = pallas_sweep.sharded_delta_state
+
+    def delta_w(base, nbrs, *a, **kw):
+        calls["batches"] += nbrs.shape[0] == replicas * mprop
+        return delta(base, nbrs, *a, **kw)
+
+    monkeypatch.setattr(pallas_sweep, "sharded_delta_state", delta_w)
+    orbits = search._circulant_orbits(n, n // fold, (1, 2, 9))
+    with jax.profiler.trace(trace_dir):
+        res = search._replica_polish(
+            n, 6, seed=seed, n_iter=n_iter, fold=fold, start_orbits=orbits,
+            engine=None, replicas=replicas, exchange_every=5, resync_every=5,
+            proposal_batch=mprop)
+    [polish] = repro_events(trace_dir)["repro.polish"]
+    [tally] = repro_events(trace_dir)["repro.polish.tally"]
+    assert inside(tally, polish)
+    kmax = 6
+    width = 4 * fold * (1 + kmax)
+    assert calls["batches"] > 0 and res.evals_delta > 0
+    assert tally[2] == {
+        "state_host_copies": 0, "column_pulls": calls["batches"],
+        "column_bytes": calls["batches"] * replicas * mprop * (n // fold)
+        * width * 4}
 
 
 def test_circulant_hillclimb_tally(trace_dir):

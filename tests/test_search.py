@@ -440,6 +440,86 @@ def test_replica_polish_resync_drift_guard():
         _resync_check([good, bad], 16, 64, use_pallas=False)
 
 
+@pytest.mark.parametrize("fold,offsets", [
+    (4, (1, 5)), (8, (1, 5)),                    # kmax 4
+    (4, (1, 5, 11, 17)), (8, (1, 5, 11, 17)),    # kmax 8
+    (4, (2, 6)),        # even and odd vertices apart: sentinel entries
+    (8, (1, 5, 32)),    # a half-size (diameter) orbit
+], ids=["f4k4", "f8k4", "f4k8", "f8k8", "disconnected", "diameter"])
+def test_column_pull_lost_parent_matches_full_state(fold, offsets):
+    """The polish's lost-parent test on columns gathered from the device
+    state (``pallas_sweep.state_columns``) gives exactly the mask of
+    ``_removal_affected_nbr`` on the full state, for every proposal of a
+    replica-major batch; an idle slot's padded columns read column 0."""
+    from repro.core.engines import pallas_sweep
+    from repro.core.graphs import circulant
+    from repro.core.search import _circulant_orbits, _orbit
+
+    n, replicas, mprop = 64, 2, 3
+    s = n // fold
+    rng = np.random.default_rng(fold + 10 * len(offsets))
+    chains = []  # (dist, nbr, chord orbits) per replica
+    for offs in (offsets, (offsets[0], offsets[1] + 2, *offsets[2:])):
+        adj = circulant(n, offs).adjacency()
+        ev = metrics.SymmetricAPSP(adj, s, engine="numpy", use_c=False)
+        chains.append((ev.dist.astype(np.int32), metrics._nbr_table(adj),
+                       sorted(_circulant_orbits(n, s, offs), key=sorted)))
+    kmax = chains[0][1].shape[1]
+    assert chains[1][1].shape[1] == kmax
+    o = offsets[1]
+    picks = {
+        # two orbits of one offset that share the endpoint o
+        0: (_orbit(n, s, 0, o), _orbit(n, s, o, 2 * o)),
+        # the orbit of the largest offset (half-size for the diameter)
+        1: (_orbit(n, s, 0, offsets[-1]), None),
+    }
+    removed_of = {}
+    for slot in range(replicas * mprop - 1):  # the last slot stays idle
+        orbs = chains[slot // mprop][2]
+        a, b = picks.get(slot, (None, None))
+        while a is None or b is None or a == b:
+            a = a or orbs[rng.integers(len(orbs))]
+            b = orbs[rng.integers(len(orbs))]
+        removed_of[slot] = sorted(set(a) | set(b))
+    if offsets[-1] == n // 2:
+        assert len(_orbit(n, s, 0, n // 2)) == fold // 2
+    if offsets[0] == 2:
+        assert (chains[0][0] == n).any()
+
+    cols = np.zeros((replicas * mprop, 4 * fold * (1 + kmax)), dtype=np.int32)
+    compact = {}
+    for slot, removed in removed_of.items():
+        cols[slot], nbr_c, removed_c = metrics._removal_columns(
+            chains[slot // mprop][1], removed, cols.shape[1])
+        compact[slot] = (nbr_c, removed_c)
+    base = pallas_sweep.stack_states([c[0] for c in chains], replicas)
+    block = pallas_sweep.state_columns(base, cols)
+    assert block.shape == (replicas * mprop, s, cols.shape[1])
+    for slot, removed in removed_of.items():
+        dist, nbr, _ = chains[slot // mprop]
+        want = metrics._removal_affected_nbr(dist, nbr, removed)
+        got = metrics._removal_affected_nbr(block[slot], *compact[slot])
+        assert np.array_equal(got, want), slot
+    idle = replicas * mprop - 1
+    assert np.array_equal(block[idle],
+                          np.repeat(chains[1][0][:, :1], cols.shape[1], 1))
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_replica_polish_delta_equals_full_at_the_cells_shape(seed):
+    """At the benchmark cells' shape (4 replicas, 2 proposals each) the
+    delta polish, its state on the device, returns the full sweep's
+    result, through a replica exchange (after iteration 10) and drift-guard
+    re-sweeps (after 5, 10 and 12)."""
+    import dataclasses
+
+    d, f = _polish_pair(64, 4, 4, seed, 4, n_iter=12, proposal_batch=2,
+                        resync_every=5)
+    counts = dict(evals_delta=0, evals_full=0, device_dispatches=0)
+    assert dataclasses.replace(d, **counts) == dataclasses.replace(f, **counts)
+    assert d.evals_delta > 0
+
+
 def test_pallas_interpret_env_override(monkeypatch):
     """REPRO_PALLAS_INTERPRET wins over platform auto-detect; unset falls
     back to the platform (compiled only on a TPU backend); set_interpret(None)
