@@ -13,8 +13,10 @@ unless ``REPRO_PALLAS_INTERPRET`` says otherwise.  The model-zoo kernels in
 entry points: R stacked neighbour tables are priced in one ``shard_map``
 over the replica axis, so each device sweeps its replicas' graphs locally
 and only per-replica scalars come home.  The delta path's distance state
-stays on the devices: ``stack_states``, ``take_slot``, ``state_columns``
-and ``states_equal`` stack, select, probe and compare it there.
+is one (R, s, n) array split over the same replica axis, so each chain's
+rows stay on the device that prices its proposals: ``spread_states``,
+``place_states``, ``copy_state``, ``state_columns`` and ``states_equal``
+start, update, exchange, probe and compare it there.
 """
 from __future__ import annotations
 
@@ -272,11 +274,11 @@ def sharded_delta_state(
     all rows affected, post-swap table, no patch.  Proposal i belongs to
     chain ``i // M`` (replica-major order, M = b // R proposals per chain).
 
-    ``base`` may be a host array or a device array (``stack_states``); a
-    device base is not copied back.  Returns ``(totals (b,) int64, maxima
-    (b,) int32, state)`` where state is the (b, s, n) post-swap
-    representative rows, a device array sharded over the replica axis
-    (callers select the accepted proposals with ``take_slot``).  Exact
+    ``base`` may be a host array or the chains' device state, split over
+    the replica axis; a device base is not copied back.  Returns ``(totals
+    (b,) int64, maxima (b,) int32, state)`` where state is the (b, s, n)
+    post-swap representative rows, a device array split over the replica
+    axis (callers select the accepted proposals with ``place_states``).  Exact
     integer hop counts: bit-identical to the full sweep, per the property
     tests.
     """
@@ -312,42 +314,155 @@ def sharded_delta_state(
 # ------------------------------------------------------------------------------
 # Device-resident chain state (the delta polish's distance rows)
 # ------------------------------------------------------------------------------
+#
+# The chains' current rows and their best snapshots are two (R, s, n) int32
+# arrays split over the replica axis, as the dispatch splits its proposals:
+# chain r lives on the mesh device that prices its proposals.  Each program
+# below is a ``shard_map`` over that mesh and runs on every device's own
+# chains with no collective.  The one copy between devices is the
+# exchange's, made by ``copy_state`` with ``jax.device_put``.
 
-def _helper(fn, **kw):
-    """``jax.jit(fn)``, cached under ``fn``'s name: the program is named
-    after it (never ``per_shard``, the dispatch's own name)."""
-    key = ("helper", fn.__name__, tuple(sorted(kw.items())))
-    out = _CACHE.get(key)
-    if out is None:
-        out = _CACHE[key] = _jax().jit(fn, **kw)
-    return out
+def replica_shards(r: int) -> int:
+    """How many devices the replica axis of ``r`` chains is split over."""
+    return _mesh(r).devices.size
 
 
-def stack_states(states, replicas: int):
-    """Stack R (s, n) chain states into the (R, s, n) dispatch base on the
-    device, sharded over the replica axis.  Device states are left where
-    they are (host ones, as tests pass, are uploaded); nothing is pulled."""
+def _state_programs(r: int) -> dict:
+    """The chain-state programs for ``r`` chains, each jitted as a
+    ``shard_map`` over ``_mesh(r)`` with every chain-indexed argument and
+    result split over the replica axis, cached per mesh.  Each program is
+    named after its body (never ``per_shard``, the dispatch's own name)."""
+    jax = _jax()
+    import jax.numpy as jnp
+    from jax import lax
+    from jax.sharding import PartitionSpec as P
+
+    mesh = _mesh(r)
+    key = ("state", r, tuple(mesh.devices.flat))
+    if key in _CACHE:
+        return _CACHE[key]
+    per = r // mesh.devices.size  # chains a device holds
+
+    def spread_states(x):
+        return jnp.broadcast_to(x, (per,) + x.shape[1:])
+
+    def place_states(cur, snap, st, take, better):
+        st = st.reshape((per, st.shape[0] // per) + st.shape[1:])
+        new = cur
+        for m in range(st.shape[1]):
+            new = jnp.where((take == m)[:, None, None], st[:, m], new)
+        return new, jnp.where(better[:, None, None], new, snap)
+
+    def pick_state(x, i):
+        # every device takes its own row nearest chain i; the one that
+        # holds chain i takes chain i
+        off = lax.axis_index("r") * per
+        return lax.dynamic_index_in_dim(x, jnp.clip(i - off, 0, per - 1),
+                                        keepdims=True)
+
+    def put_state(x, row, j):
+        hit = jnp.arange(per) == j - lax.axis_index("r") * per
+        return jnp.where(hit[:, None, None], row, x)
+
+    def state_columns(st, cc):
+        b, c = cc.shape
+        m = b // per
+        g = jax.vmap(lambda x, ci: x[:, ci])(st, cc.reshape(per, m * c))
+        return g.reshape(per, -1, m, c).transpose(0, 2, 1, 3).reshape(
+            b, -1, c)
+
+    def states_equal(x, y):
+        return jnp.all(x == y, axis=(1, 2))
+
+    split, whole = P("r"), P()
+
+    def local(body, in_specs, out_specs):
+        return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                                     out_specs=out_specs, check_vma=False))
+
+    progs = _CACHE[key] = {
+        "spread": local(spread_states, (split,), split),
+        "place": local(place_states, (split,) * 5, (split, split)),
+        "pick": local(pick_state, (split, whole), split),
+        "put": local(put_state, (split, split, whole), split),
+        "columns": local(state_columns, (split, split), split),
+        "equal": local(states_equal, (split, split), split),
+    }
+    return progs
+
+
+def spread_states(rows, r: int):
+    """The (r, s, n) chain state that starts every chain from one state:
+    ``rows`` is (d, s, n), d = ``replica_shards(r)``, one copy of the state
+    made on each replica device, and each device repeats its copy over its
+    own chains."""
+    return _state_programs(r)["spread"](rows)
+
+
+def place_states(base, best, states, take: np.ndarray, better: np.ndarray):
+    """Each chain's rows and best snapshot after a dispatch, in one program.
+
+    ``base`` and ``best`` are the (R, s, n) chain state and snapshots,
+    ``states`` the dispatch's (R*M, s, n) post-swap rows (replica-major),
+    ``take`` (R,) int32 the proposal (0..M-1) that chain r accepted, -1
+    where it kept its rows, and ``better`` (R,) bool where the chain's
+    snapshot takes its new rows.  Returns the new ``(base, best)``, split
+    as ``base``: every chain's rows stay on its device."""
+    return _state_programs(base.shape[0])["place"](
+        base, best, states, np.asarray(take, dtype=np.int32),
+        np.asarray(better, dtype=bool))
+
+
+def prepare_states(base, mprop: int) -> None:
+    """Compile the placement and exchange programs for chain states shaped
+    and split as ``base`` and M = ``mprop`` proposals a chain, once per
+    shape: a walk's first accept or exchange (which may come only after
+    ``exchange_every`` iterations) then compiles nothing."""
+    jax = _jax()
     import jax.numpy as jnp
 
-    def stack_states(xs):
-        return jnp.stack(xs)
+    r, s, n = base.shape
+    key = ("prepared", r, s, n, mprop, tuple(_mesh(r).devices.flat))
+    if key in _CACHE:
+        return
+    split = _sharding(r, "r")
 
-    sharding = _sharding(replicas, "r")
-    xs = _jax().device_put(list(states), _sharding(replicas))
-    return _helper(stack_states, out_shardings=sharding)(xs)
+    def dev(shape):  # a device array split over the replica axis
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=split)
+
+    def host(shape, dtype=jnp.int32):  # a numpy argument
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    progs = _state_programs(r)
+    progs["place"].lower(dev(base.shape), dev(base.shape),
+                         dev((r * mprop, s, n)), host((r,)),
+                         host((r,), jnp.bool_)).compile()
+    progs["pick"].lower(dev(base.shape), host(())).compile()
+    progs["put"].lower(dev(base.shape), dev((replica_shards(r), s, n)),
+                       host(())).compile()
+    _CACHE[key] = True
 
 
-def take_slot(states, slot: int, replicas: int):
-    """Proposal ``slot``'s (s, n) rows of a (b, s, n) device state, kept on
-    the device and replicated over the replica mesh.  The slot is a traced
-    argument, so one program serves every slot."""
-    from jax import lax
+def copy_state(dst, src, i: int, j: int):
+    """``dst`` with chain j's rows replaced by chain i's rows of ``src``
+    (both (R, s, n), split over the replica axis), and the bytes that
+    crossed between devices: none where both chains live on one device,
+    one (s, n) block where they do not."""
+    jax = _jax()
 
-    def take_slot(st, i):
-        return lax.dynamic_index_in_dim(st, i, keepdims=False)
-
-    row = _helper(take_slot)(states, np.int32(slot))
-    return _jax().device_put(row, _sharding(replicas))
+    r = dst.shape[0]
+    per = r // replica_shards(r)
+    progs = _state_programs(r)
+    rows = progs["pick"](src, np.int32(i))  # (d, s, n): chain i on its device
+    moved = 0
+    if i // per != j // per:
+        at = {(sh.index[0].start or 0): sh for sh in rows.addressable_shards}
+        row = jax.device_put(at[i // per].data, at[j // per].device)
+        moved = row.nbytes
+        rows = jax.make_array_from_single_device_arrays(
+            rows.shape, rows.sharding,
+            [row if pos == j // per else sh.data for pos, sh in at.items()])
+    return progs["put"](dst, rows, np.int32(j)), moved
 
 
 def state_columns(base, cols: np.ndarray) -> np.ndarray:
@@ -356,25 +471,14 @@ def state_columns(base, cols: np.ndarray) -> np.ndarray:
     ``base`` is the (R, s, n) device state, ``cols`` (b, C) int32 vertex
     columns with proposal i reading chain ``i // (b // R)`` (replica-major,
     as the dispatch).  Returns the (b, s, C) int32 host array
-    ``out[i] = base[i // M][:, cols[i]]``."""
-    jax = _jax()
-
-    def state_columns(st, cc):
-        r, s, _ = st.shape
-        b, c = cc.shape
-        m = b // r
-        g = jax.vmap(lambda x, ci: x[:, ci])(st, cc.reshape(r, m * c))
-        return g.reshape(r, s, m, c).transpose(0, 2, 1, 3).reshape(b, s, c)
-
-    return np.asarray(_helper(state_columns)(base, cols))
+    ``out[i] = base[i // M][:, cols[i]]``; each device gathers its own
+    chains' columns."""
+    return np.asarray(_state_programs(base.shape[0])["columns"](
+        base, np.asarray(cols, dtype=np.int32)))
 
 
 def states_equal(a, b) -> np.ndarray:
     """(R,) bool: whether each replica's (s, n) rows of two (R, s, n)
-    device states agree bit for bit; only the flags leave the device."""
-    import jax.numpy as jnp
-
-    def states_equal(x, y):
-        return jnp.all(x == y, axis=(1, 2))
-
-    return np.asarray(_helper(states_equal)(a, b))
+    states agree bit for bit, compared where the chains live; only the
+    flags leave the devices."""
+    return np.asarray(_state_programs(a.shape[0])["equal"](a, b))

@@ -1000,15 +1000,12 @@ def symmetric_sa_search(
 class _PolishChain:
     """One replica of the device-priced orbit polish: host-side orbit state
     plus the padded neighbour table the device sweep prices from.  Under
-    delta pricing the chain also holds its representative-row distance
-    state (``dist``, a (s, n) int32 device array) and the ``best_dist``
-    snapshot replica exchange restores from; the lost-parent test reads
-    only the columns it needs, gathered on the device.  Both are rebound,
-    never mutated in place, so snapshots are safe by reference."""
+    delta pricing its representative-row distance state and best snapshot
+    are row r of the polish's two (R, s, n) device arrays, not fields
+    here."""
 
     __slots__ = ("rng", "orb_list", "chord_edges", "adj", "nbr",
-                 "cur_mpl", "cur_d", "best_orbits", "best_mpl", "best_d", "t",
-                 "dist", "best_dist")
+                 "cur_mpl", "cur_d", "best_orbits", "best_mpl", "best_d", "t")
 
     def __init__(self, rng, orb_list, adj, t_start):
         self.rng = rng
@@ -1020,7 +1017,6 @@ class _PolishChain:
         self.cur_mpl = self.cur_d = float("inf")
         self.best_orbits = set(self.orb_list)
         self.best_mpl = self.best_d = float("inf")
-        self.dist = self.best_dist = None
 
     def trial_nbr(self, removed, added) -> np.ndarray:
         """Neighbour table of the proposal graph (degrees are conserved by
@@ -1052,21 +1048,20 @@ class _PolishChain:
         self.cur_mpl, self.cur_d = mpl, d
 
 
-def _resync_check(chains, s: int, n: int, use_pallas: bool) -> None:
+def _resync_check(base, nbrs: np.ndarray, n: int, use_pallas: bool) -> None:
     """Drift guard for the delta-priced polish: re-sweep every chain's
-    current graph from scratch in one dispatch and assert the maintained
-    incremental distance state matches bit-for-bit.  The comparison runs
-    where the state lives (host states are uploaded); one flag per replica
-    comes back.  Raises ``AssertionError`` on any divergence."""
+    current graph (``nbrs``, (R, n, kmax)) from scratch in one dispatch and
+    assert the maintained incremental distance state ``base`` ((R, s, n))
+    matches bit-for-bit.  The comparison runs where the state lives (a host
+    state is uploaded); one flag per replica comes back.  Raises
+    ``AssertionError`` on any divergence."""
     from .engines import pallas_sweep
 
+    r, s, _ = base.shape
     with obs.span("repro.polish.resync"):
-        base = pallas_sweep.stack_states([ch.dist for ch in chains],
-                                         len(chains))
-        nbrs = np.stack([ch.nbr for ch in chains]).astype(np.int32, copy=False)
         _, _, state = pallas_sweep.sharded_delta_state(
-            base, nbrs, [np.arange(s)] * len(chains), [None] * len(chains), n,
-            use_pallas=use_pallas)
+            base, nbrs.astype(np.int32, copy=False), [np.arange(s)] * r,
+            [None] * r, n, use_pallas=use_pallas)
         for r, same in enumerate(pallas_sweep.states_equal(state, base)):
             if not same:
                 raise AssertionError(
@@ -1128,11 +1123,22 @@ def _replica_polish(
     Every ``exchange_every`` iterations the globally best state replaces the
     worst non-protected chain, exactly like ``sa_search``.
 
-    Under a profiler the call records ``repro.polish.tally``: whole chain
-    states handed from host memory to the device inside the iteration loop
-    (``state_host_copies``; the loop pulls none back), and the column
-    gathers (``column_pulls``) with the bytes they pulled
-    (``column_bytes``).
+    Under delta pricing the chains' current rows and best snapshots are
+    two (R, s, n) device arrays split over the replica mesh, each chain's
+    rows on the device that prices its proposals.  After each dispatch one
+    program (``pallas_sweep.place_states``) takes every chain's accepted
+    post-swap rows into both, on the device where they were priced; the
+    exchange's copy of one chain's best rows into another chain is the
+    only state that crosses between devices, and only where the two
+    chains live on different ones.
+
+    Under a profiler the call records ``repro.polish.place`` spans (the
+    placement program and the exchange's copy) and the
+    ``repro.polish.tally`` mark: the column gathers (``column_pulls``)
+    with the bytes they pulled (``column_bytes``), the placement calls
+    (``place_calls``, one per ``place`` span), and the chain states copied
+    between devices (``state_moves``) with their bytes
+    (``state_move_bytes``).
     """
     from .engines import pallas_sweep
 
@@ -1160,14 +1166,16 @@ def _replica_polish(
                       for r in range(replicas)]
             norm = s * (n - 1)
             dispatches = 1
-            # all chains share the warm start: one stacked pricing seeds cur/best
+            # all chains share the warm start: priced once on each replica
+            # device (the same pricing, so the same rows), it seeds cur/best
             if delta:
+                nd = pallas_sweep.replica_shards(replicas)
                 tot0, mx0, st0 = pallas_sweep.sharded_delta_state(
-                    np.zeros((1, s, n), dtype=np.int32), np.stack([chains[0].nbr]),
-                    [np.arange(s)], [None], n, use_pallas=use_pallas)
-                dist0 = pallas_sweep.take_slot(st0, 0, replicas)
-                for ch in chains:
-                    ch.dist, ch.best_dist = dist0, dist0
+                    np.zeros((nd, s, n), dtype=np.int32),
+                    np.stack([chains[0].nbr] * nd), [np.arange(s)] * nd,
+                    [None] * nd, n, use_pallas=use_pallas)
+                base = best_rows = pallas_sweep.spread_states(st0, replicas)
+                pallas_sweep.prepare_states(base, proposal_batch)
             else:
                 tot0, mx0 = pallas_sweep.sharded_rows_totals(
                     np.stack([chains[0].nbr]), s, n, use_pallas=use_pallas)
@@ -1190,7 +1198,8 @@ def _replica_polish(
             # neighbours; one gather shape per configuration
             cols = np.zeros((bsz, 4 * fold * (1 + chains[0].nbr.shape[1])),
                             dtype=np.int32)
-            host_copies = column_pulls = column_bytes = 0
+            column_pulls = column_bytes = 0
+            place_calls = state_moves = state_move_bytes = 0
         for it in range(n_iter):
             proposals: list = [None] * bsz
             srcs: list = [empty] * bsz
@@ -1230,10 +1239,6 @@ def _replica_polish(
                             cols[slot], nbr_c, removed_c = metrics._removal_columns(
                                 ch.nbr, removed, cols.shape[1])
                             compact[slot] = (nbr_c, removed_c)
-                        host_copies += sum(isinstance(ch.dist, np.ndarray)
-                                           for ch in chains)
-                        base = pallas_sweep.stack_states(
-                            [ch.dist for ch in chains], replicas)
                         block = pallas_sweep.state_columns(base, cols)
                         column_pulls += 1
                         column_bytes += block.nbytes
@@ -1262,6 +1267,8 @@ def _replica_polish(
                         nbr_stack, s, n, use_pallas=use_pallas)
                     states = None
                 dispatches += 1
+                take = np.full(replicas, -1, dtype=np.int32)
+                better = np.zeros(replicas, dtype=bool)
                 with obs.span("repro.polish.accept"):
                     for r, ch in enumerate(chains):
                         committed = False
@@ -1282,19 +1289,21 @@ def _replica_polish(
                                 tn = ch.trial_nbr(removed, added)
                             ch.commit(removed, added, work_list, work_chords, tn,
                                       new_mpl, new_d)
-                            if delta:
-                                ch.dist = pallas_sweep.take_slot(states, slot,
-                                                                 replicas)
+                            take[r] = m
                             committed = True
                             accepted += 1
                             if (ch.cur_mpl, ch.cur_d) < (ch.best_mpl, ch.best_d):
                                 ch.best_orbits = set(ch.orb_list)
                                 ch.best_mpl, ch.best_d = ch.cur_mpl, ch.cur_d
-                                if delta:
-                                    ch.best_dist = ch.dist
+                                better[r] = True
                                 if (ch.best_mpl, ch.best_d) < global_best:
                                     global_best = (ch.best_mpl, ch.best_d)
                                     history.append(ch.best_mpl)
+                if delta and (take >= 0).any():
+                    with obs.span("repro.polish.place"):
+                        base, best_rows = pallas_sweep.place_states(
+                            base, best_rows, states, take, better)
+                    place_calls += 1
                 if replicas > 1 and (it + 1) % exchange_every == 0 and it + 1 < n_iter:
                     with obs.span("repro.polish.exchange"):
                         gb = min(range(replicas),
@@ -1310,16 +1319,21 @@ def _replica_polish(
                             ch.nbr = metrics._nbr_table(ch.adj)
                             ch.cur_mpl, ch.cur_d = chains[gb].best_mpl, chains[gb].best_d
                             if delta:
-                                ch.dist = chains[gb].best_dist
+                                with obs.span("repro.polish.place"):
+                                    base, moved = pallas_sweep.copy_state(
+                                        base, best_rows, gb, worst)
+                                place_calls += 1
+                                state_moves += moved > 0
+                                state_move_bytes += moved
             if delta and (it + 1 == n_iter
                           or (resync_every and (it + 1) % resync_every == 0)):
-                host_copies += sum(isinstance(ch.dist, np.ndarray)
-                                   for ch in chains)
-                _resync_check(chains, s, n, use_pallas)
+                _resync_check(base, np.stack([ch.nbr for ch in chains]), n,
+                              use_pallas)
                 dispatches += 1
 
-        obs.mark("repro.polish.tally", state_host_copies=host_copies,
-                 column_pulls=column_pulls, column_bytes=column_bytes)
+        obs.mark("repro.polish.tally", column_pulls=column_pulls,
+                 column_bytes=column_bytes, place_calls=place_calls,
+                 state_moves=state_moves, state_move_bytes=state_move_bytes)
         with obs.span("repro.polish.finish"):
             gb = min(range(replicas),
                      key=lambda r: (chains[r].best_mpl, chains[r].best_d, r))

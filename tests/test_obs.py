@@ -96,24 +96,42 @@ def _tiny_polish(**kw):
         replicas=2, exchange_every=5, resync_every=4, proposal_batch=2, **kw)
 
 
+def _count_calls(monkeypatch, calls: dict, batch: int) -> None:
+    """Count, apart from the spans, the dispatches that price a whole batch
+    of ``batch`` proposals (one per iteration with a proposal) and the
+    calls of the placement program and of the exchange's copy."""
+    delta = pallas_sweep.sharded_delta_state
+    place, copy = pallas_sweep.place_states, pallas_sweep.copy_state
+
+    def delta_w(base, nbrs, *a, **kw):
+        calls["batches"] += nbrs.shape[0] == batch
+        return delta(base, nbrs, *a, **kw)
+
+    def place_w(*a):
+        calls["place"] += 1
+        return place(*a)
+
+    def copy_w(*a):
+        calls["copy"] += 1
+        return copy(*a)
+
+    calls.update(batches=0, place=0, copy=0)
+    monkeypatch.setattr(pallas_sweep, "sharded_delta_state", delta_w)
+    monkeypatch.setattr(pallas_sweep, "place_states", place_w)
+    monkeypatch.setattr(pallas_sweep, "copy_state", copy_w)
+
+
 def test_replica_polish_stage_spans(trace_dir, monkeypatch):
     """One propose and one accept per iteration, one exchange per
     ``exchange_every``, one resync per ``resync_every`` plus the last, one
     column gather inside the propose of every iteration with a proposal,
-    one pack per dispatch; every stage inside ``repro.polish``; the traced
+    one pack per dispatch, one place per placement call and exchange copy,
+    after the accept; every stage inside ``repro.polish``; the traced
     result equals the untraced one."""
     untraced = _tiny_polish()
 
-    # iterations with a proposal, counted apart from the spans: the
-    # dispatches that price a whole batch (replicas x proposal_batch)
-    calls = {"batches": 0}
-    delta = pallas_sweep.sharded_delta_state
-
-    def delta_w(base, nbrs, *a, **kw):
-        calls["batches"] += nbrs.shape[0] == 2 * 2
-        return delta(base, nbrs, *a, **kw)
-
-    monkeypatch.setattr(pallas_sweep, "sharded_delta_state", delta_w)
+    calls: dict = {}
+    _count_calls(monkeypatch, calls, batch=2 * 2)
     with jax.profiler.trace(trace_dir):
         traced = _tiny_polish()
     assert traced == untraced
@@ -131,6 +149,8 @@ def test_replica_polish_stage_spans(trace_dir, monkeypatch):
     assert 0 < calls["batches"] <= n_iter
     assert count(ev, "repro.polish.columns") == calls["batches"]
     assert count(ev, "repro.polish.pull") == 0
+    assert 0 < calls["place"] <= calls["batches"]
+    assert count(ev, "repro.polish.place") == calls["place"] + calls["copy"]
     # one run and one pack per dispatch: the state is stacked on the device
     assert count(ev, "repro.dispatch.run") == traced.device_dispatches \
         == 1 + calls["batches"] + resyncs
@@ -143,23 +163,19 @@ def test_replica_polish_stage_spans(trace_dir, monkeypatch):
     for run in ev["repro.dispatch.run"]:
         assert not any(inside(run, p) for p in proposes)
         assert not any(inside(run, a) for a in ev["repro.polish.accept"])
+    for place in ev["repro.polish.place"]:
+        assert not any(inside(place, a) for a in ev["repro.polish.accept"])
 
 
 @pytest.mark.parametrize("seed", [0, 4])
 def test_replica_polish_tally(trace_dir, monkeypatch, seed):
-    """At the benchmark cells' shape (4 replicas, 2 proposals each) the
-    chains' state never crosses to the host inside the loop: the tally
-    reads no host copy of a state, one column gather per iteration with a
-    proposal, and that gather's bytes."""
+    """At the benchmark cells' shape (4 replicas, 2 proposals each) on one
+    device: the tally reads one column gather per iteration with a
+    proposal and that gather's bytes, one placement call per placement
+    program and exchange copy, and no chain state moved between devices."""
     n, fold, replicas, mprop, n_iter = 64, 4, 4, 2, 12
-    calls = {"batches": 0}
-    delta = pallas_sweep.sharded_delta_state
-
-    def delta_w(base, nbrs, *a, **kw):
-        calls["batches"] += nbrs.shape[0] == replicas * mprop
-        return delta(base, nbrs, *a, **kw)
-
-    monkeypatch.setattr(pallas_sweep, "sharded_delta_state", delta_w)
+    calls: dict = {}
+    _count_calls(monkeypatch, calls, batch=replicas * mprop)
     orbits = search._circulant_orbits(n, n // fold, (1, 2, 9))
     with jax.profiler.trace(trace_dir):
         res = search._replica_polish(
@@ -171,11 +187,13 @@ def test_replica_polish_tally(trace_dir, monkeypatch, seed):
     assert inside(tally, polish)
     kmax = 6
     width = 4 * fold * (1 + kmax)
-    assert calls["batches"] > 0 and res.evals_delta > 0
+    assert calls["batches"] > 0 and res.evals_delta > 0 and calls["copy"] > 0
     assert tally[2] == {
-        "state_host_copies": 0, "column_pulls": calls["batches"],
+        "column_pulls": calls["batches"],
         "column_bytes": calls["batches"] * replicas * mprop * (n // fold)
-        * width * 4}
+        * width * 4,
+        "place_calls": calls["place"] + calls["copy"],
+        "state_moves": 0, "state_move_bytes": 0}
 
 
 def test_circulant_hillclimb_tally(trace_dir):

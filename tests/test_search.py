@@ -1,5 +1,7 @@
 """Topology discovery (paper Algorithm 1 + tiers): optimal-MPL targets from
 TABLE 1/2 must be reached; determinism per seed; bound gaps at 256 nodes."""
+import json
+
 import numpy as np
 import pytest
 
@@ -291,19 +293,89 @@ def test_replica_polish_pallas_and_jnp_device_paths_identical():
     assert a.mpl == b.mpl and a.accepted == b.accepted
 
 
-def test_replica_polish_multi_device_invariant(devices8):
+@pytest.mark.parametrize("replicas", [4, 16])
+def test_replica_polish_multi_device_invariant(devices8, replicas):
     """Sharding the replica axis over real (forced-host) devices changes
-    the placement, never the math: 4 devices reproduce the 1-device run."""
-    res = search.large_search(48, 4, seed=0, budget=10, fold=4, replicas=4,
-                              exchange_every=10)
-    out = devices8("""
+    the placement, never the math: 4 devices reproduce the 1-device run,
+    with the delta dispatch and with the full sweep, whether each device
+    holds one chain or four."""
+    kw = dict(seed=0, budget=10, fold=4, replicas=replicas, exchange_every=10)
+    res = search.large_search(48, 4, **kw)
+    out = devices8(f"""
         from repro.core import search
-        res = search.large_search(48, 4, seed=0, budget=10, fold=4, replicas=4,
-                                  exchange_every=10)
-        print(res.mpl, res.diameter, res.accepted, hash(res.graph.edges))
+        for delta in (True, False):
+            res = search.large_search(48, 4, delta=delta, **{kw!r})
+            print(res.mpl, res.diameter, res.accepted, res.history,
+                  hash(res.graph.edges))
     """, n_devices=4)
-    assert out.strip() == \
-        f"{res.mpl} {res.diameter} {res.accepted} {hash(res.graph.edges)}"
+    want = (f"{res.mpl} {res.diameter} {res.accepted} {res.history} "
+            f"{hash(res.graph.edges)}")
+    assert out.strip().splitlines() == [want, want]
+
+
+def test_replica_polish_state_split_over_devices(devices8):
+    """Sixteen chains on four devices: after the walk the chains' rows and
+    best snapshots are each one (16, s, n) array split over the replica
+    axis, four chains a device and none replicated, and the tally counts
+    as moved exactly the exchanges whose two chains live on different
+    devices, one (s, n) int32 block each."""
+    out = devices8("""
+        import glob, json, tempfile
+        import jax
+        from jax.profiler import ProfileData
+        from jax.sharding import PartitionSpec as P
+        from repro.core import search
+        from repro.core.engines import pallas_sweep
+
+        n, fold, replicas = 64, 4, 16
+        seen = {"exchanges": [], "arrays": []}
+        place, copy = pallas_sweep.place_states, pallas_sweep.copy_state
+
+        def place_w(*a):
+            base, best = place(*a)
+            seen["arrays"][:] = [base, best]
+            return base, best
+
+        def copy_w(dst, src, i, j):
+            seen["exchanges"].append((i, j))
+            base, moved = copy(dst, src, i, j)
+            seen["arrays"][0] = base
+            return base, moved
+
+        pallas_sweep.place_states, pallas_sweep.copy_state = place_w, copy_w
+        orbits = search._circulant_orbits(n, n // fold, (1, 2, 9))
+        tdir = tempfile.mkdtemp()
+        with jax.profiler.trace(tdir):
+            search._replica_polish(
+                n, 6, seed=2, n_iter=12, fold=fold, start_orbits=orbits,
+                engine=None, replicas=replicas, exchange_every=3,
+                proposal_batch=2)
+        [path] = glob.glob(tdir + "/**/*.xplane.pb", recursive=True)
+        tally = [dict(e.stats) for p in ProfileData.from_file(path).planes
+                 for line in p.lines for e in line.events
+                 if e.name == "repro.polish.tally"]
+        shards = [[list(s.data.shape) for s in a.addressable_shards]
+                  for a in seen["arrays"]]
+        print(json.dumps({
+            "specs": [str(a.sharding.spec) for a in seen["arrays"]],
+            "replicated": [a.sharding.is_fully_replicated
+                           for a in seen["arrays"]],
+            "shards": shards,
+            "devices": [len({s.device for s in a.addressable_shards})
+                        for a in seen["arrays"]],
+            "exchanges": seen["exchanges"], "tally": tally}))
+    """, n_devices=4)
+    got = json.loads(out.strip().splitlines()[-1])
+    s, n = 16, 64
+    assert got["specs"] == ["PartitionSpec('r',)"] * 2
+    assert got["replicated"] == [False, False]
+    assert got["shards"] == [[[4, s, n]] * 4] * 2
+    assert got["devices"] == [4, 4]
+    crossed = sum(i // 4 != j // 4 for i, j in got["exchanges"])
+    assert 0 < crossed < len(got["exchanges"])  # both kinds were taken
+    [tally] = got["tally"]
+    assert tally["state_moves"] == crossed
+    assert tally["state_move_bytes"] == crossed * s * n * 4
 
 
 def _polish_pair(n, k, fold, seed, replicas, engine=None, n_iter=25, **kw):
@@ -425,19 +497,17 @@ def test_replica_polish_resync_drift_guard():
                           exchange_every=8, delta=True, resync_every=4)
     assert res.mpl < float("inf")  # every in-walk resync was clean
 
-    class _Chain:
-        def __init__(self, dist, nbr):
-            self.dist, self.nbr = dist, nbr
-
     from repro.core.graphs import circulant
     adj = circulant(64, (1, 2, 9)).adjacency()
     ev = metrics.SymmetricAPSP(adj, 16, engine="numpy", use_c=False)
-    good = _Chain(ev.dist.astype(np.int32), metrics._nbr_table(adj))
-    _resync_check([good], 16, 64, use_pallas=False)  # exact state: no raise
-    bad = _Chain(good.dist.copy(), good.nbr)
-    bad.dist[3, 17] += 1  # simulated drift
-    with pytest.raises(AssertionError, match="drift"):
-        _resync_check([good, bad], 16, 64, use_pallas=False)
+    good = ev.dist.astype(np.int32)
+    nbr = metrics._nbr_table(adj)
+    _resync_check(good[None], nbr[None], 64, use_pallas=False)  # exact: no raise
+    bad = good.copy()
+    bad[3, 17] += 1  # simulated drift
+    with pytest.raises(AssertionError, match="drift: replica 1"):
+        _resync_check(np.stack([good, bad]), np.stack([nbr, nbr]), 64,
+                      use_pallas=False)
 
 
 @pytest.mark.parametrize("fold,offsets", [
@@ -492,7 +562,7 @@ def test_column_pull_lost_parent_matches_full_state(fold, offsets):
         cols[slot], nbr_c, removed_c = metrics._removal_columns(
             chains[slot // mprop][1], removed, cols.shape[1])
         compact[slot] = (nbr_c, removed_c)
-    base = pallas_sweep.stack_states([c[0] for c in chains], replicas)
+    base = np.stack([c[0] for c in chains])
     block = pallas_sweep.state_columns(base, cols)
     assert block.shape == (replicas * mprop, s, cols.shape[1])
     for slot, removed in removed_of.items():

@@ -120,3 +120,65 @@ def test_jax_circulant_sweep_compiles_for_v5e(one_chip):
     fn = jax_circulant._jax_sweep(N, m)
     compiled = fn.lower(_sds((jax_circulant.CHUNK, m), one_chip)).compile()
     assert compiled.memory_analysis().peak_memory_in_bytes > 0
+
+
+COLLECTIVES = ("all-gather", "all-reduce", "collective-permute", "all-to-all",
+               "reduce-scatter")
+
+
+def test_replica_state_programs_compile_for_four_chips(topo):
+    """Sixteen chains over the four chips of the described host (the
+    ``polish4`` deployment): the delta dispatch and every chain-state
+    program compile with the state split four chains a chip, and none
+    holds a collective: each chip prices, places, probes and compares its
+    own chains.  The exchange's one copy between chips is a
+    ``jax.device_put`` outside every program."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.core.engines import pallas_sweep
+
+    r, mprop = 16, 2
+    b = r * mprop
+    cols = 4 * FOLD * (1 + K)
+    try:
+        pallas_sweep.set_interpret(False)
+        with pallas_sweep.replica_devices(topo.devices):
+            assert pallas_sweep.replica_shards(r) == 4
+            rs = NamedSharding(pallas_sweep._mesh(r), P("r"))
+            state = _sds((r, S, N), rs)
+            progs = pallas_sweep._state_programs(r)
+            compiled = {
+                "spread": progs["spread"].lower(_sds((4, S, N), rs)),
+                "place": progs["place"].lower(
+                    state, state, _sds((b, S, N), rs),
+                    jax.ShapeDtypeStruct((r,), jnp.int32),
+                    jax.ShapeDtypeStruct((r,), jnp.bool_)),
+                "pick": progs["pick"].lower(
+                    state, jax.ShapeDtypeStruct((), jnp.int32)),
+                "put": progs["put"].lower(
+                    state, _sds((4, S, N), rs),
+                    jax.ShapeDtypeStruct((), jnp.int32)),
+                "columns": progs["columns"].lower(
+                    state, jax.ShapeDtypeStruct((b, cols), jnp.int32)),
+                "equal": progs["equal"].lower(state, state),
+            }
+            fn = pallas_sweep._sharded_delta_fn(
+                r, mprop, N, K, S, MAX_LANES, MAX_ENDPOINTS, MAX_EDGES, N,
+                use_pallas=True)
+            args = [state, _sds((b, N, K), rs), _sds((b, MAX_LANES), rs)]
+            args += [_sds((b, MAX_ENDPOINTS), rs)] * 3
+            args += [_sds((b, MAX_ENDPOINTS), rs, jnp.bool_)]
+            args += [_sds((b, MAX_EDGES), rs)] * 3
+            compiled["dispatch"] = fn.lower(*args)
+            compiled = {k: v.compile() for k, v in compiled.items()}
+    finally:
+        pallas_sweep.set_interpret(None)
+    assert _has_kernel(compiled["dispatch"])
+    for name, c in compiled.items():
+        text = c.as_text()
+        assert not [op for op in COLLECTIVES if op in text], name
+    # each chip's placement holds its four chains' rows, snapshots and
+    # post-swap rows, never the sixteen
+    chain = S * N * 4
+    assert compiled["place"].memory_analysis().argument_size_in_bytes \
+        < (4 + 4 + 8 + 1) * chain
