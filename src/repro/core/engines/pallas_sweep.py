@@ -1,9 +1,12 @@
 """``engine="pallas"`` — the device BFS sweep (``kernels.bfs_sweep``).
 
 The same level-synchronous BFS as the host bitset engine, run as a Pallas
-TPU kernel: each grid cell keeps a (n, 128-source) distance block resident
+TPU kernel: each grid cell keeps the distance state of 128 sources resident
 in VMEM for the whole level loop and reads neighbour indices as scalars from
-SMEM.  Whether the kernels run compiled or in Pallas interpret mode is
+SMEM.  The row kernel advances one vertex a step.  The delta dispatch's
+graphs are invariant under rotation by s, and where their fold allows
+(``bfs_sweep.sweep_fold``) the orbit kernel sweeps them, advancing a
+vertex's whole orbit a step.  Whether the kernels run compiled or in Pallas interpret mode is
 resolved in one place, ``_default_interpret``: compiled exactly when JAX's
 default backend is a TPU, interpret mode elsewhere (the CPU test suite),
 unless ``REPRO_PALLAS_INTERPRET`` says otherwise.  The model-zoo kernels in
@@ -130,12 +133,18 @@ def _sharding(r: int, *spec):
 
 
 def _sweep(use_pallas: bool, interpret: bool, n: int, kmax: int, lanes: int,
-           sentinel: int):
+           sentinel: int, s: int | None = None):
     """(bs, n, kmax) tables, (bs, lanes) sources -> (bs, lanes, n) rows:
-    the Pallas kernel or its vmapped jnp twin."""
+    a Pallas kernel or the vmapped jnp twin.  Given ``s``, every table is
+    invariant under rotation by ``s`` and every source is below it, and the
+    orbit kernel sweeps where ``bfs_sweep.sweep_fold`` allows; the row
+    kernel sweeps otherwise."""
     jax = _jax()
     from ...kernels import bfs_sweep
 
+    if use_pallas and bfs_sweep.sweep_fold(n, s) > 1:
+        return lambda nb, src: bfs_sweep._pallas_orbit_sweep(
+            nb.shape[0], n, s, kmax, lanes, sentinel, interpret)(nb, src)
     if use_pallas:
         return lambda nb, src: bfs_sweep._pallas_sweep(
             nb.shape[0], n, kmax, lanes, sentinel, interpret)(nb, src)
@@ -222,7 +231,7 @@ def _sharded_delta_fn(r: int, mprop: int, n: int, kmax: int, s: int,
     fn = _CACHE.get(key)
     if fn is not None:
         return fn
-    sweep = _sweep(use_pallas, interpret, n, kmax, lanes, sentinel)
+    sweep = _sweep(use_pallas, interpret, n, kmax, lanes, sentinel, s)
 
     def per_shard(base, nb, src, crow_src, crow_shift, pts_idx, pmask, add_i,
                   add_j, add_w):
@@ -281,6 +290,13 @@ def sharded_delta_state(
     axis (callers select the accepted proposals with ``place_states``).  Exact
     integer hop counts: bit-identical to the full sweep, per the property
     tests.
+
+    Every table in ``nbrs`` must be invariant under rotation by s, as the
+    patch's rolled endpoint rows assume too, and every source is a
+    representative row below s: the orbit sweep kernel reads only the
+    first s rows of a table.  The ``repro.dispatch.run`` span counts
+    ``sweep_fold``: the fold the orbit kernel swept with, or 1 where the
+    rows were swept otherwise.
     """
     from ...kernels import bfs_sweep
 
@@ -302,7 +318,8 @@ def sharded_delta_state(
     # waits for the upload and the whole program.  A host base is uploaded
     # and a device base left where it is, with one placement either way, so
     # both reach the same compiled program
-    with obs.span("repro.dispatch.run"):
+    fold = bfs_sweep.sweep_fold(n, s) if use_pallas else 1
+    with obs.span("repro.dispatch.run", sweep_fold=fold):
         base = _jax().device_put(base, _sharding(r, "r"))
         rowsums, mx, state = _sharded_delta_fn(
             r, b // r, n, kmax, s, src.shape[1], mmax, amax, sentinel,

@@ -14,17 +14,30 @@ source vertices, idle lanes holding ``n`` (no vertex).
   split into lower/upper halves — TPU vector units have no 64-bit lanes),
   advanced by word-parallel OR/AND-NOT gathers.  It runs on any backend and
   under ``vmap``.
-* ``_pallas_sweep`` is the TPU kernel.  Grid ``(graph, source block)``;
-  one cell owns ``LANES`` = 128 sources (one int32 vreg row per vertex) and
-  keeps its ``(n, 128)`` distance state resident in VMEM for the whole level
-  loop (4 MiB at n = 8192).  A level is a row loop over vertices: the
-  neighbour indices are read as scalars from SMEM, staged from HBM
-  ``NB_ROWS`` vertices at a time, and each neighbour's distance row is a
-  dynamic single-row load.  Vertex ``v`` is reached at level ``d + 1`` in
-  lane j iff it is unreached and some neighbour sits at exactly ``d``; rows
-  are updated in place, which is safe because a row written during level d
-  holds ``d + 1`` and can never match ``== d``.  The finished block is
-  copied to HBM vertex-major and transposed to ``(lanes, n)`` by XLA.
+* ``_pallas_sweep`` is the row kernel, for any graph.  Grid ``(graph,
+  source block)``; one cell owns ``LANES`` = 128 sources (one int32 vreg
+  row per vertex) and keeps its ``(n, 128)`` distance state resident in
+  VMEM for the whole level loop (4 MiB at n = 8192).  A level is a row loop
+  over vertices: the neighbour indices are read as scalars from SMEM,
+  staged from HBM ``NB_ROWS`` vertices at a time, and each neighbour's
+  distance row is a dynamic single-row load.  Vertex ``v`` is reached at
+  level ``d + 1`` in lane j iff it is unreached and some neighbour sits at
+  exactly ``d``; rows are updated in place, which is safe because a row
+  written during level d holds ``d + 1`` and can never match ``== d``.
+  The finished block is copied to HBM vertex-major and transposed to
+  ``(lanes, n)`` by XLA.
+* ``_pallas_orbit_sweep`` is the orbit kernel, for graphs invariant under
+  rotation by ``s`` (the symmetric polish's, swept from sources below
+  ``s``).  The neighbours of ``v = q * s + i`` are ``{(u + q * s) mod n :
+  u in nb[i]}``, so the state is one ``(h, 128)`` tile per residue ``i``,
+  ``h = max(8, fold)``, whose row q holds vertex ``(q mod fold) * s + i``,
+  and neighbour slot k of the whole orbit is the tile of residue
+  ``u mod s`` rolled along the orbit axis by ``u // s``.  A level loops
+  over the s residues with whole-tile loads, not over the n vertices, by
+  the same in-place rule; only the first s table rows are read, packed as
+  (residue, roll) pairs and staged in SMEM once a cell where they fit.
+  ``sweep_fold`` says where it applies: a fold of at least 2 that divides
+  8 or is a multiple of 8, so the tile meets the (8, 128) tiling.
 
 Both return exact integer hop counts, with ``sentinel`` for unreachable
 pairs, so they are bit-identical (property tests in ``tests/test_kernels.py``
@@ -41,6 +54,16 @@ import numpy as np
 WORD = 32  # uint32 packing of the jnp twin (no 64-bit vector lanes on TPU)
 LANES = 128  # sources per sweep-kernel grid cell: one int32 vreg row
 NB_ROWS = 1024  # neighbour-table rows staged in SMEM per copy (kmax * 4 KiB)
+# the orbit kernel's packed table entry: residue << _ROT_BITS | rotation
+_ROT_BITS = 16
+_ROT_MASK = (1 << _ROT_BITS) - 1
+# residues the orbit kernel advances between two rounds of stores: on a v5e
+# at 512 source lanes, 2 swept n = 8192 (fold 8) and n = 4096 (fold 4)
+# 1.16x and 1.18x faster than 1, and 1.12x and 1.04x faster than 4
+ORBIT_GROUP = 2
+# an orbit kernel state larger than this (fold 2 or 4 at large n, up to 4x
+# the row kernel's) asks the compiler for its own size plus this much VMEM
+SWEEP_VMEM_STATE = 8 << 20
 # in-kernel "not reached yet": never equal to a level counter (<= n), mapped
 # to the caller's sentinel after the sweep
 UNREACHED = np.int32(np.iinfo(np.int32).max)
@@ -65,6 +88,7 @@ __all__ = [
     "patch_apply_ref",
     "patch_prologue",
     "source_lanes",
+    "sweep_fold",
     "sweep_rows_ref",
 ]
 
@@ -250,6 +274,165 @@ def _pallas_sweep(b: int, n: int, kmax: int, lanes: int, sentinel: int,
                        constant_values=n)
         out = call(srcp.reshape(b, nblk, 1, LANES), nbp)
         out = out.transpose(0, 1, 3, 2).reshape(b, nblk * LANES, n)[:, :lanes]
+        return jnp.where(out == UNREACHED, sentinel, out)
+
+    fn = jax.jit(sweep)
+    _CACHE[key] = fn
+    return fn
+
+
+def sweep_fold(n: int, s: int | None) -> int:
+    """The fold ``n / s`` the orbit kernel sweeps a graph invariant under
+    rotation by ``s`` with, or 1 where the row kernel does: the orbit
+    kernel needs a whole fold of at least 2 that divides 8 or is a
+    multiple of 8, so that an ``(8, 128)``-aligned tile of
+    ``max(8, fold)`` rows holds the orbit a whole number of times."""
+    if not s or n % s:
+        return 1
+    fold = n // s
+    return fold if fold >= 2 and (8 % fold == 0 or fold % 8 == 0) else 1
+
+
+def _orbit_kernel(src_ref, tab_hbm, out_hbm, dist_ref, tab_smem, *, s, fold,
+                  kmax, rows):
+    # one grid cell = one (graph, 128-source block); dist_ref is the
+    # (s_pad, h, blk) state, one tile per residue i whose row q holds vertex
+    # (q mod fold) * s + i, and tab_smem the staged slice of the packed
+    # (residue, rotation) table
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    r = pl.program_id(0)
+    _, h, blk = dist_ref.shape
+    n_chunks = dist_ref.shape[0] // rows
+    dist_ref[...] = jnp.full(dist_ref.shape, UNREACHED, jnp.int32)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (h, blk), 1)
+    orbit_row = jax.lax.broadcasted_iota(jnp.int32, (h, blk), 0)
+    # the rows that hold vertex 0 * s + i of the orbit (every copy of it)
+    head = functools.reduce(jnp.logical_or,
+                            [orbit_row == q for q in range(0, h, fold)])
+
+    def seed(j, carry):
+        v = src_ref[0, 0, 0, j]
+
+        @pl.when(v < s)
+        def _():
+            dist_ref[v] = jnp.where(head & (lane == j), 0, dist_ref[v])
+
+        return carry
+
+    jax.lax.fori_loop(0, blk, seed, 0)
+    if n_chunks == 1:  # the whole table fits: stage it once, not per level
+        pltpu.sync_copy(tab_hbm.at[r, 0], tab_smem)
+
+    def level(st):
+        d, _ = st
+
+        def chunk(c, acc):
+            if n_chunks > 1:
+                pltpu.sync_copy(tab_hbm.at[r, c], tab_smem)
+
+            def group(g, acc):
+                # every hit of the group is read before any of its tiles is
+                # written, so the loads need not wait for the stores: an
+                # entry written at level d holds d + 1, never d, so the
+                # hits come out the same in either order
+                upd = []
+                for t in range(ORBIT_GROUP):
+                    li = g * ORBIT_GROUP + t
+                    cur = dist_ref[c * rows + li]
+                    hit = jnp.zeros((h, blk), jnp.bool_)
+                    for k in range(kmax):  # static unroll: kmax = max degree
+                        code = tab_smem[0, li * kmax + k]
+                        # neighbour slot k of the whole orbit: the tile of
+                        # the neighbour's residue, rotated along the orbit
+                        nbr = pltpu.roll(dist_ref[code >> _ROT_BITS],
+                                         code & _ROT_MASK, 0)
+                        hit = hit | (nbr == d)
+                    upd.append((li, cur, hit & (cur == UNREACHED)))
+                for li, cur, new in upd:
+                    dist_ref[c * rows + li] = jnp.where(new, d + 1, cur)
+                    acc = acc | new.astype(jnp.int32)
+                return acc
+
+            return jax.lax.fori_loop(0, rows // ORBIT_GROUP, group, acc)
+
+        acc = jax.lax.fori_loop(0, n_chunks, chunk,
+                                jnp.zeros((h, blk), jnp.int32))
+        return d + 1, jnp.max(acc) > 0
+
+    jax.lax.while_loop(lambda st: st[1], level,
+                       (jnp.int32(0), jnp.bool_(True)))
+    pltpu.sync_copy(dist_ref.at[pl.ds(0, s)], out_hbm.at[r, pl.program_id(1)])
+
+
+def _pallas_orbit_sweep(b: int, n: int, s: int, kmax: int, lanes: int,
+                        sentinel: int, interpret: bool):
+    """Compiled batched sweep of graphs invariant under rotation by ``s``,
+    by vertex orbit: the wire format of ``_pallas_sweep`` ((b, n, kmax)
+    tables, (b, lanes) sources below ``s``, idle lanes at ``n``) ->
+    (b, lanes, n) int32 distances.  Only the first ``s`` rows of each
+    table are read; ``sweep_fold(n, s)`` must be at least 2."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    fold = n // s
+    h = max(8, fold)  # whole (8, 128) tiles: the orbit repeats h / fold times
+    # both halves of a packed entry fit: residue < 2^15, rotation < 2^16
+    assert sweep_fold(n, s) == fold > 1 and s >> (31 - _ROT_BITS) == 0 \
+        and h <= _ROT_MASK, (n, s)
+    # whole groups of residues a chunk, NB_ROWS at most
+    rows = min(-(-s // ORBIT_GROUP) * ORBIT_GROUP, NB_ROWS)
+    key = ("orbit", b, n, s, kmax, lanes, sentinel, rows, ORBIT_GROUP,
+           interpret)
+    fn = _CACHE.get(key)
+    if fn is not None:
+        return fn
+    nblk = -(-lanes // LANES)
+    s_pad = -(-s // rows) * rows
+    state = s_pad * h * LANES * 4
+    params = None
+    if state > SWEEP_VMEM_STATE:
+        params = pltpu.CompilerParams(
+            vmem_limit_bytes=state + SWEEP_VMEM_STATE)
+    call = pl.pallas_call(
+        functools.partial(_orbit_kernel, s=s, fold=fold, kmax=kmax,
+                          rows=rows),
+        grid=(b, nblk),
+        in_specs=[
+            pl.BlockSpec((1, 1, 1, LANES), lambda r, i: (r, i, 0, 0),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pltpu.HBM),
+        ],
+        out_specs=pl.BlockSpec(memory_space=pltpu.HBM),
+        out_shape=jax.ShapeDtypeStruct((b, nblk, s, h, LANES), jnp.int32),
+        scratch_shapes=[pltpu.VMEM((s_pad, h, LANES), jnp.int32),
+                        pltpu.SMEM((1, rows * kmax), jnp.int32)],
+        compiler_params=params,
+        interpret=interpret,
+    )
+
+    def sweep(nb, src):
+        # neighbour u of residue i is, for the whole orbit of i, residue
+        # u mod s with the orbit rotated by u // s; pad residues
+        # (s <= i < s_pad) loop to themselves and are never reached
+        u = nb[:, :s, :]
+        code = (u % s) << _ROT_BITS | (-(u // s)) % h
+        pad = jnp.broadcast_to(
+            (jnp.arange(s, s_pad, dtype=jnp.int32) << _ROT_BITS)[None, :, None],
+            (b, s_pad - s, kmax))
+        tab = jnp.concatenate([code, pad], axis=1).reshape(
+            b, s_pad // rows, 1, rows * kmax)
+        srcp = jnp.pad(src, ((0, 0), (0, nblk * LANES - lanes)),
+                       constant_values=n)
+        out = call(srcp.reshape(b, nblk, 1, LANES), tab)[:, :, :, :fold]
+        # (graph, block, residue, orbit row, lane) -> vertex q * s + i
+        out = out.transpose(0, 1, 4, 3, 2).reshape(b, nblk * LANES, n)
+        out = out[:, :lanes]
         return jnp.where(out == UNREACHED, sentinel, out)
 
     fn = jax.jit(sweep)

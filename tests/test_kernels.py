@@ -143,3 +143,123 @@ def test_sharded_rows_totals_match_host():
                                                    use_pallas=use_pallas)
         assert np.array_equal(tot, want.sum((1, 2), dtype=np.int64)), use_pallas
         assert np.array_equal(mx, want.max((1, 2))), use_pallas
+
+
+def _orbit_graph(fold: int, s: int, chords: int, odd_kmax: bool, seed: int,
+                 disconnected: bool = False) -> np.ndarray:
+    """Padded (n, kmax) neighbour table of a random graph on n = fold * s
+    vertices that is invariant under rotation by s: a ring and ``chords``
+    random chord orbits a residue (or, ``disconnected``, only the edges
+    (i, i + s), so that no orbit reaches another).  At least one pad
+    column, and kmax odd or even as asked."""
+    rng = np.random.default_rng(seed)
+    n = fold * s
+    adj = [set() for _ in range(n)]
+
+    def add_orbit(u, v):
+        for q in range(0, n, s):
+            a, b = (u + q) % n, (v + q) % n
+            if a != b:
+                adj[a].add(b)
+                adj[b].add(a)
+
+    for i in range(s):
+        if disconnected:
+            add_orbit(i, i + s)
+            continue
+        add_orbit(i, i + 1)
+        for _ in range(chords):
+            add_orbit(i, int(rng.integers(n)))
+    deg = max(len(a) for a in adj)
+    kmax = deg + 1 if (deg + 1) % 2 == odd_kmax else deg + 2
+    nbr = np.full((n, kmax), -1, dtype=np.int32)
+    for v, a in enumerate(adj):
+        nbr[v, :len(a)] = sorted(a)
+    return nbr
+
+
+# fold, s, chords, odd kmax, sources of the two graphs, disconnected
+ORBIT_CASES = [
+    (2, 150, 2, True, (150, 131), False),  # two 128-lane blocks
+    (4, 12, 1, False, (7, 12), False),  # idle lanes
+    (8, 20, 1, True, (20, 3), True),  # sentinels
+    (16, 9, 2, False, (9, 4), False),
+    (8, 13, 2, False, (13, 13), False),
+]
+
+
+@pytest.mark.parametrize("fold,s,chords,odd,ms,disconnected", ORBIT_CASES)
+def test_orbit_sweep_matches_row_sweep_and_oracle(fold, s, chords, odd, ms,
+                                                  disconnected):
+    """The orbit kernel, the row kernel and the jnp twin give the same
+    hop counts, sentinels and idle lanes included, on graphs invariant
+    under rotation by s (s not a power of two, kmax odd and even, pad
+    entries, more than 128 source lanes)."""
+    from repro.kernels import bfs_sweep
+
+    nbr = _orbit_graph(fold, s, chords, odd, seed=fold * s,
+                       disconnected=disconnected)
+    n, kmax = nbr.shape
+    assert kmax % 2 == odd and (nbr < 0).any()
+    rng = np.random.default_rng(s)
+    srcs = [np.sort(rng.choice(s, m, replace=False)) for m in ms]
+    nb, src = bfs_sweep.pack_sweep(np.stack([nbr, nbr]), srcs)
+    b, lanes = src.shape
+    assert bfs_sweep.sweep_fold(n, s) == fold
+    oracle = np.asarray(jax.jit(jax.vmap(
+        lambda t, x: bfs_sweep.sweep_rows_ref(t, x, n)))(nb, src))
+    orbit = np.asarray(bfs_sweep._pallas_orbit_sweep(
+        b, n, s, kmax, lanes, n, interpret=True)(nb, src))
+    rows = np.asarray(bfs_sweep._pallas_sweep(
+        b, n, kmax, lanes, n, interpret=True)(nb, src))
+    assert np.array_equal(orbit, oracle)
+    assert np.array_equal(rows, oracle)
+    for r, x in enumerate(srcs):
+        # sentinels where an orbit reaches no other; idle lanes sweep nothing
+        assert (orbit[r, :len(x)] == n).any() == disconnected
+        assert (orbit[r, len(x):] == n).all()
+
+
+def test_orbit_sweep_stages_the_table_in_chunks(monkeypatch):
+    """Where s residues do not fit one SMEM staging buffer, the table is
+    staged a chunk at a time, every level, with the same result."""
+    from repro.kernels import bfs_sweep
+
+    monkeypatch.setattr(bfs_sweep, "NB_ROWS", 8)
+    nbr = _orbit_graph(4, 21, 2, True, seed=3)
+    n, kmax = nbr.shape
+    nb, src = bfs_sweep.pack_sweep(nbr[None], [np.arange(21)])
+    oracle = np.asarray(jax.jit(bfs_sweep.sweep_rows_ref, static_argnums=2)(
+        nb[0], src[0], n))
+    orbit = bfs_sweep._pallas_orbit_sweep(1, n, 21, kmax, src.shape[1], n,
+                                          interpret=True)
+    assert np.array_equal(np.asarray(orbit(nb, src))[0], oracle)
+
+
+def test_sweep_picks_the_kernel_by_shape(monkeypatch):
+    """The delta dispatch's sweep takes the orbit kernel where the fold
+    n / s is 2 or more and divides 8 or is a multiple of it, the row kernel
+    otherwise (fold 1, folds 3, 6 and 12, no s), and the jnp twin with the
+    Pallas kernels off."""
+    from repro.core.engines import pallas_sweep
+    from repro.kernels import bfs_sweep
+
+    took = []
+    monkeypatch.setattr(bfs_sweep, "_pallas_orbit_sweep",
+                        lambda *a, **k: lambda nb, src: took.append("orbit"))
+    monkeypatch.setattr(bfs_sweep, "_pallas_sweep",
+                        lambda *a, **k: lambda nb, src: took.append("row"))
+    n = 96
+    for s, want in [(48, "orbit"), (24, "orbit"), (12, "orbit"),
+                    (6, "orbit"), (96, "row"), (32, "row"), (16, "row"),
+                    (8, "row"), (None, "row")]:
+        fold = n // s if s else 1
+        assert bfs_sweep.sweep_fold(n, s) == (fold if want == "orbit" else 1)
+        took.clear()
+        pallas_sweep._sweep(True, True, n, 4, 32, n, s)(
+            np.zeros((1, n, 4), np.int32), np.zeros((1, 32), np.int32))
+        assert took == [want], (s, took)
+    assert bfs_sweep.sweep_fold(100, 30) == 1  # no whole fold
+    took.clear()
+    pallas_sweep._sweep(False, True, n, 4, 32, n, 12)
+    assert took == []

@@ -167,6 +167,23 @@ def test_replica_polish_stage_spans(trace_dir, monkeypatch):
         assert not any(inside(place, a) for a in ev["repro.polish.accept"])
 
 
+@pytest.mark.parametrize("engine,fold,want", [("pallas", 8, 8),
+                                               ("bitset", 4, 1)])
+def test_dispatch_run_counts_the_sweep_fold(trace_dir, engine, fold, want):
+    """Every ``repro.dispatch.run`` carries ``sweep_fold``: the fold the
+    orbit sweep kernel took, 1 where the rows were swept otherwise (here
+    the jnp twin)."""
+    n = 12 * fold
+    orbits = search._circulant_orbits(n, 12, (2, 5))
+    with jax.profiler.trace(trace_dir):
+        res = search._replica_polish(
+            n, 6, seed=3, n_iter=3, fold=fold, start_orbits=orbits,
+            engine=engine, replicas=2, exchange_every=5, delta=True)
+    runs = repro_events(trace_dir)["repro.dispatch.run"]
+    assert len(runs) == res.device_dispatches > 0
+    assert [r[2] for r in runs] == [{"sweep_fold": want}] * len(runs)
+
+
 @pytest.mark.parametrize("seed", [0, 4])
 def test_replica_polish_tally(trace_dir, monkeypatch, seed):
     """At the benchmark cells' shape (4 replicas, 2 proposals each) on one

@@ -425,6 +425,23 @@ def test_replica_polish_delta_pallas_matches_jnp_twin():
     assert a.evals_delta == b.evals_delta and a.evals_full == b.evals_full
 
 
+def test_replica_polish_delta_pallas_matches_jnp_twin_at_fold_8():
+    """At fold 8 (the n = 8192 cells' fold) the orbit sweep kernel's tile is
+    one orbit of eight vertices: the Pallas and jnp-twin trajectories are
+    still identical."""
+    from repro.core.search import _circulant_orbits, _replica_polish
+
+    orbits = _circulant_orbits(96, 12, (2, 9))
+    run = lambda eng: _replica_polish(  # noqa: E731
+        96, 4, seed=1, n_iter=20, fold=8, start_orbits=orbits, engine=eng,
+        replicas=2, exchange_every=10, delta=True)
+    a, b = run("pallas"), run("bitset")
+    assert a.device_dispatches > 0 and a.evals_delta > 0
+    assert a.graph.edges == b.graph.edges
+    assert a.mpl == b.mpl and a.history == b.history
+    assert a.evals_delta == b.evals_delta and a.evals_full == b.evals_full
+
+
 def test_replica_polish_proposal_batch():
     """proposal_batch=M prices M swaps per chain per dispatch and accepts
     greedily in lockstep order: M=1 reproduces the unbatched trajectory

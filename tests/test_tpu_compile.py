@@ -66,6 +66,13 @@ def _has_kernel(compiled) -> bool:
     return "tpu_custom_call" in compiled.as_text()
 
 
+def _has_sweep_kernel(compiled) -> bool:
+    """A Pallas custom call named after the jitted ``sweep``: the name the
+    benchmark's sweep kernel time reads (``jit_per_shard/sweep.N``)."""
+    return any(line.lstrip().startswith("%sweep.") and "tpu_custom_call" in line
+               for line in compiled.as_text().splitlines())
+
+
 @pytest.mark.parametrize("b,lanes", [(8, 32), (8, MAX_LANES)])
 def test_sweep_kernel_compiles_for_v5e(one_chip, b, lanes):
     from repro.kernels import bfs_sweep
@@ -73,6 +80,21 @@ def test_sweep_kernel_compiles_for_v5e(one_chip, b, lanes):
     fn = bfs_sweep._pallas_sweep(b, N, K, lanes, N, interpret=False)
     compiled = fn.lower(_sds((b, N, K), one_chip),
                         _sds((b, lanes), one_chip)).compile()
+    assert _has_kernel(compiled)
+
+
+# n, k, s: the polish cells' folds 8 and 4 (a 4-row tile of fold 4 is
+# refused as unaligned: the kernel's tile repeats the orbit to 8 rows), and
+# fold 2 at n = 16384, whose 32 MiB state the kernel asks VMEM room for
+@pytest.mark.parametrize("n,k,s,lanes", [(N, K, S, 32), (N, K, S, MAX_LANES),
+                                         (4096, 6, 1024, 512),
+                                         (2 * N, K, N, 128)])
+def test_orbit_sweep_kernel_compiles_for_v5e(one_chip, n, k, s, lanes):
+    from repro.kernels import bfs_sweep
+
+    fn = bfs_sweep._pallas_orbit_sweep(8, n, s, k, lanes, n, interpret=False)
+    compiled = fn.lower(_sds((8, n, k), one_chip),
+                        _sds((8, lanes), one_chip)).compile()
     assert _has_kernel(compiled)
 
 
@@ -110,7 +132,36 @@ def test_delta_step_compiles_for_v5e(topo):
             compiled = fn.lower(*args).compile()
     finally:
         pallas_sweep.set_interpret(None)
-    assert _has_kernel(compiled)
+    assert _has_kernel(compiled) and _has_sweep_kernel(compiled)
+
+
+def test_delta_step_compiles_for_v5e_at_fold_4(topo):
+    """The whole delta dispatch at n = 4096, k = 6, fold 4 (the TPU v4 pod
+    cell), whose orbit tiles hold each orbit twice.  The sweep is still
+    the custom call named after the jitted ``sweep``, which the
+    benchmark's kernel time reads."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.core.engines import pallas_sweep
+
+    n, k, s, mmax, amax = 4096, 6, 1024, 16, 8
+    r, mprop = 4, 2
+    b = r * mprop
+    try:
+        pallas_sweep.set_interpret(False)
+        with pallas_sweep.replica_devices(topo.devices[:1]):
+            rs = NamedSharding(pallas_sweep._mesh(r), P("r"))
+            fn = pallas_sweep._sharded_delta_fn(
+                r, mprop, n, k, s, s, mmax, amax, n, use_pallas=True)
+            args = [_sds((r, s, n), rs), _sds((b, n, k), rs),
+                    _sds((b, s), rs)]
+            args += [_sds((b, mmax), rs)] * 3
+            args += [_sds((b, mmax), rs, jnp.bool_)]
+            args += [_sds((b, amax), rs)] * 3
+            compiled = fn.lower(*args).compile()
+    finally:
+        pallas_sweep.set_interpret(None)
+    assert _has_sweep_kernel(compiled)
 
 
 def test_jax_circulant_sweep_compiles_for_v5e(one_chip):
