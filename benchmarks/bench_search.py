@@ -303,58 +303,6 @@ def run(smoke: bool = False) -> common.Rows:
             "spec": _spec_dict(spec_p),
         })
 
-    # --- delta-priced device replica polish vs the full-sweep dispatch ------
-    # Both runs walk the identical per-seed replica-polish trajectory (the
-    # proposal RNG and accept rule never see which pricer ran), so
-    # engine_mpl == mpl is asserted and speedup isolates the pricing
-    # algorithm: incremental APSP (affected-rows re-sweep + min-plus patch,
-    # `sharded_delta_state`) against the full representative-row sweep.
-    # engine=None resolves to a host engine, so the device dispatch runs the
-    # jitted jnp twins — the speedup > 1 contract CI asserts holds in
-    # interpret/jnp mode, not just on real devices.  jit compiles ride in
-    # both timed regions (they are small next to interpreted execution, and
-    # warm-up runs would double the row's wall cost).  fold=8 rather than 16:
-    # the full sweep prices 2x the representative rows while the delta cost
-    # (affected rows + patch endpoints) stays flat, which is exactly the
-    # regime the incremental tier exists for.
-    for (n, k, fold, iters, m) in ([(8192, 8, 8, 4, 2)]
-                                   if smoke else [(8192, 8, 8, 8, 2)]):
-        lb = metrics.mpl_lower_bound(n, k)
-        spec_d = SearchSpec.make(n, k, seed=0, strategy="large", budget=iters,
-                                 fold=fold, replicas=2, polish_iters=iters,
-                                 exchange_every=max(2, iters // 2),
-                                 proposal_batch=m, delta=True)
-        spec_f = spec_d.with_overrides(
-            params={**spec_d.kwargs, "delta": False})
-        t0 = time.perf_counter()
-        res_d = api.search(spec_d)
-        delta_s = time.perf_counter() - t0
-        t0 = time.perf_counter()
-        res_f = api.search(spec_f)
-        full_s = time.perf_counter() - t0
-        assert res_d.mpl == res_f.mpl, "delta pricing diverged from full sweep"
-        speedup = full_s / delta_s if delta_s > 0 else float("inf")
-        from repro.core.engines import pallas_sweep
-        interp = pallas_sweep.get_interpret()
-        rows.add(f"polish_n{n}_k{k}_delta", delta_s,
-                 f"{iters} orbit iters fold={fold} replicas=2 batch={m} "
-                 f"delta={delta_s:.3f}s (interpret={interp}) full={full_s:.3f}s "
-                 f"speedup={speedup:.2f}x mpl={res_d.mpl:.4f} lb={lb:.4f} "
-                 f"delta_evals={res_d.evals_delta} full_evals={res_d.evals_full} "
-                 f"dispatches={res_d.device_dispatches}")
-        results.append({
-            "name": f"polish_n{n}_k{k}_delta", "n": n, "k": k, "fold": fold,
-            "iters": iters, "replicas": 2, "proposal_batch": m,
-            "baseline": "full-sweep", "interpret": interp,
-            "engine_s": round(delta_s, 4), "seed_s": round(full_s, 4),
-            "speedup": round(speedup, 2),
-            "engine_mpl": res_d.mpl, "mpl": res_f.mpl, "mpl_lb": lb,
-            "gap_pct": round((res_d.mpl / lb - 1) * 100, 2),
-            "evals_delta": res_d.evals_delta, "evals_full": res_d.evals_full,
-            "device_dispatches": res_d.device_dispatches,
-            "spec": _spec_dict(spec_d),
-        })
-
     # --- co-design tier: objective="collective-time" ------------------------
     # fig4_schedule: the searched topology + its synthesized allreduce
     # schedule (repro.comm.schedules) against the legacy ring schedule on the

@@ -12,12 +12,12 @@ default backend is a TPU, interpret mode elsewhere (the CPU test suite),
 unless ``REPRO_PALLAS_INTERPRET`` says otherwise.  The model-zoo kernels in
 ``repro.kernels.ops`` use the same resolver.
 
-``sharded_rows_totals`` and ``sharded_delta_state`` are the replica-polish
-entry points: R stacked neighbour tables are priced in one ``shard_map``
-over the replica axis, so each device sweeps its replicas' graphs locally
-and only per-replica scalars come home.  The delta path's distance state
-is one (R, s, n) array split over the same replica axis, so each chain's
-rows stay on the device that prices its proposals: ``spread_states``,
+``sharded_delta_state`` is the replica polish's one pricing entry point:
+R*M stacked neighbour tables are priced in one ``shard_map`` over the
+replica axis, so each device sweeps its replicas' graphs locally and only
+per-proposal scalars come home.  The chains' distance state is one
+(R, s, n) array split over the same replica axis, so each chain's rows
+stay on the device that prices its proposals: ``spread_states``,
 ``place_states``, ``copy_state``, ``state_columns`` and ``states_equal``
 start, update, exchange, probe and compare it there.
 """
@@ -110,7 +110,7 @@ class PallasEngine(Engine):
 
 
 # ------------------------------------------------------------------------------
-# Replica-sharded batched pricing (large_search replica polish)
+# Replica mesh (large_search replica polish)
 # ------------------------------------------------------------------------------
 
 def _mesh(r: int):
@@ -150,65 +150,6 @@ def _sweep(use_pallas: bool, interpret: bool, n: int, kmax: int, lanes: int,
             nb.shape[0], n, kmax, lanes, sentinel, interpret)(nb, src)
     return jax.vmap(functools.partial(bfs_sweep.sweep_rows_ref,
                                       sentinel=sentinel))
-
-
-def _sharded_fn(r: int, n: int, kmax: int, lanes: int, m: int,
-                sentinel: int, use_pallas: bool):
-    jax = _jax()
-    import jax.numpy as jnp
-    from jax.sharding import PartitionSpec as P
-
-    interpret = get_interpret()
-    mesh = _mesh(r)
-    key = ("sharded", r, n, kmax, lanes, m, sentinel, use_pallas, interpret,
-           tuple(mesh.devices.flat))
-    fn = _CACHE.get(key)
-    if fn is not None:
-        return fn
-    sweep = _sweep(use_pallas, interpret, n, kmax, lanes, sentinel)
-
-    def per_shard(nb, src):
-        rows = sweep(nb, src)[:, :m, :]
-        # per-source sums fit int32 only while n * sentinel <= 2^31 - 1
-        # (n <= 46340 with sentinel == n — guarded in sharded_rows_totals);
-        # the int64 grand total is finished on the host, where x64 is on
-        return (rows.sum(2, dtype=jnp.int32), rows.max((1, 2)))
-
-    fn = jax.jit(jax.shard_map(
-        per_shard, mesh=mesh, in_specs=(P("r"), P("r")),
-        out_specs=(P("r"), P("r")), check_vma=False))
-    _CACHE[key] = fn
-    return fn
-
-
-def sharded_rows_totals(
-    nbrs: np.ndarray,
-    n_sources: int,
-    sentinel: int,
-    use_pallas: bool = True,
-) -> tuple[np.ndarray, np.ndarray]:
-    """Price R stacked graphs on the device mesh in one dispatch.
-
-    ``nbrs`` is (R, n, kmax) padded neighbour tables; BFS runs from sources
-    ``0..n_sources-1`` of every graph (the representative rows of the
-    symmetric tier).  Returns (totals (R,) int64, maxima (R,) int32) of the
-    (n_sources, n) distance rows — exactly what the polish accept rule needs,
-    so only 2R scalars leave the devices.
-    """
-    from ...kernels import bfs_sweep
-
-    r, n, kmax = nbrs.shape
-    m = n_sources
-    if n * sentinel > np.iinfo(np.int32).max:
-        # the device reduction accumulates per-source row sums in int32
-        # (jax x64 is off); one row sums to at most n * sentinel
-        raise NotImplementedError(
-            f"device pricing needs n * sentinel <= int32 max (n={n}, "
-            f"sentinel={sentinel})")
-    nb, src = bfs_sweep.pack_sweep(nbrs, [np.arange(m)] * r)
-    rowsums, mx = _sharded_fn(r, n, kmax, src.shape[1], m, sentinel,
-                              use_pallas)(nb, src)
-    return np.asarray(rowsums).sum(1, dtype=np.int64), np.asarray(mx)
 
 
 # ------------------------------------------------------------------------------
@@ -273,8 +214,8 @@ def sharded_delta_state(
 ):
     """Price b = R*M proposal graphs *incrementally* in one device dispatch.
 
-    The delta twin of ``sharded_rows_totals``: instead of re-sweeping every
-    representative row of every proposal, each proposal re-sweeps only its
+    Instead of re-sweeping every representative row of every proposal,
+    each proposal re-sweeps only its
     ``sources_list[i]`` rows (the affected set from the host-side batched
     lost-parent test) on its ``nbrs[i]`` (n, kmax) table — the post-removal
     graph — merges them into its chain's ``base`` (R, s, n) rows, and applies
@@ -288,8 +229,8 @@ def sharded_delta_state(
     (b,) int64, maxima (b,) int32, state)`` where state is the (b, s, n)
     post-swap representative rows, a device array split over the replica
     axis (callers select the accepted proposals with ``place_states``).  Exact
-    integer hop counts: bit-identical to the full sweep, per the property
-    tests.
+    integer hop counts: the totals and maxima are a from-scratch BFS's, per
+    the property tests.
 
     Every table in ``nbrs`` must be invariant under rotation by s, as the
     patch's rolled endpoint rows assume too, and every source is a

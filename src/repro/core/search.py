@@ -997,52 +997,56 @@ def symmetric_sa_search(
 # Tier 3c: device-sharded replica polish (shard_map over the replica axis)
 # --------------------------------------------------------------------------------
 
+def _nbr_of_edges(n: int, edges) -> np.ndarray:
+    """Padded (n, kmax) neighbour table (pad -1, kmax the largest degree)
+    of the graph on n vertices with the undirected ``edges``: each row's
+    neighbours ascending, exactly ``metrics._nbr_table`` of its adjacency,
+    built without one."""
+    e = np.fromiter(itertools.chain.from_iterable(edges), dtype=np.int64)
+    a, b = e[0::2], e[1::2]
+    # both directions as one sorted, deduplicated key: row-major (u, v)
+    u, v = np.divmod(np.unique(np.concatenate([a * n + b, b * n + a])), n)
+    deg = np.bincount(u, minlength=n)
+    nbr = np.full((n, max(1, int(deg.max()))), -1, dtype=np.int32)
+    nbr[u, np.arange(len(u)) - np.repeat(np.cumsum(deg) - deg, deg)] = v
+    return nbr
+
+
 class _PolishChain:
     """One replica of the device-priced orbit polish: host-side orbit state
-    plus the padded neighbour table the device sweep prices from.  Under
-    delta pricing its representative-row distance state and best snapshot
-    are row r of the polish's two (R, s, n) device arrays, not fields
-    here."""
+    plus the padded neighbour table the device sweep prices from.  Its
+    representative-row distance state and best snapshot are row r of the
+    polish's two (R, s, n) device arrays, not fields here.  No field is
+    written in place: a move replaces ``orb_list``, ``chord_edges`` and
+    ``nbr``, so chains may start from shared ones."""
 
-    __slots__ = ("rng", "orb_list", "chord_edges", "adj", "nbr",
+    __slots__ = ("rng", "orb_list", "chord_edges", "nbr",
                  "cur_mpl", "cur_d", "best_orbits", "best_mpl", "best_d", "t")
 
-    def __init__(self, rng, orb_list, adj, t_start):
+    def __init__(self, rng, orb_list, chord_edges, nbr, t_start):
         self.rng = rng
-        self.orb_list = list(orb_list)
-        self.chord_edges = {e for orb in orb_list for e in orb}
-        self.adj = adj
-        self.nbr = metrics._nbr_table(adj)
+        self.orb_list = orb_list
+        self.chord_edges = chord_edges
+        self.nbr = nbr
         self.t = t_start
         self.cur_mpl = self.cur_d = float("inf")
         self.best_orbits = set(self.orb_list)
         self.best_mpl = self.best_d = float("inf")
 
     def trial_nbr(self, removed, added) -> np.ndarray:
-        """Neighbour table of the proposal graph (degrees are conserved by
+        """Neighbour table of the proposal graph: the rows of the touched
+        endpoints rebuilt from their current rows (degrees are conserved by
         the orbit-size check, so kmax never grows)."""
-        for u, v in removed:
-            self.adj[u, v] = self.adj[v, u] = False
-        for u, v in added:
-            self.adj[u, v] = self.adj[v, u] = True
-        try:
-            out = self.nbr.copy()
-            for u in sorted({x for e in (*removed, *added) for x in e}):
-                ws = np.nonzero(self.adj[u])[0]
-                out[u, :] = -1
-                out[u, : len(ws)] = ws
-            return out
-        finally:
-            for u, v in added:
-                self.adj[u, v] = self.adj[v, u] = False
-            for u, v in removed:
-                self.adj[u, v] = self.adj[v, u] = True
+        out = self.nbr.copy()
+        gone = set(removed) | {(v, u) for u, v in removed}
+        for u in sorted({x for e in (*removed, *added) for x in e}):
+            ws = sorted([w for w in out[u].tolist() if w >= 0 and (u, w) not in gone]
+                        + [b if a == u else a for a, b in added if u in (a, b)])
+            out[u, :] = -1
+            out[u, : len(ws)] = ws
+        return out
 
-    def commit(self, removed, added, work_list, work_chords, nbr, mpl, d):
-        for u, v in removed:
-            self.adj[u, v] = self.adj[v, u] = False
-        for u, v in added:
-            self.adj[u, v] = self.adj[v, u] = True
+    def commit(self, work_list, work_chords, nbr, mpl, d):
         self.nbr = nbr
         self.orb_list, self.chord_edges = work_list, work_chords
         self.cur_mpl, self.cur_d = mpl, d
@@ -1081,7 +1085,6 @@ def _replica_polish(
     exchange_every: int = 50,
     t_start: float = 0.05,
     t_end: float = 1e-4,
-    delta: bool = True,
     proposal_batch: int = 1,
     resync_every: int = 64,
     full_rebuild_frac: float = 0.9,
@@ -1097,23 +1100,28 @@ def _replica_polish(
     when the resolved engine is the device sweep, their jnp twins otherwise)
     and only per-proposal (total, max) scalars come home.
 
-    With ``delta=True`` (default) the dispatch is the incremental-APSP twin
-    ``sharded_delta_state``: each chain's representative-row distances stay
-    on the device.  Once all proposals are drawn, one gather pulls the
-    columns the lost-parent test reads (each removed endpoint and its
-    neighbours) from each proposal's chain state; the test, run on the
-    host on those compact blocks, marks the rows a removal touches, and
-    the device re-sweeps only those rows on
-    the post-removal graph before min-plus patching the added edges back in
-    — the ``SymmetricAPSP`` algorithm, vectorized over proposals.  An
-    accepted proposal's post-swap rows are selected on the device; no
-    whole state crosses to the host.  Proposals
-    whose affected set exceeds ``full_rebuild_frac`` of the rows (or whose
-    base is disconnected) fall back to a full re-sweep expressed in the same
+    The dispatch is the incremental-APSP twin ``sharded_delta_state``:
+    each chain's representative-row distances stay on the device.  Once
+    all proposals are drawn, one gather pulls the columns the lost-parent
+    test reads (each removed endpoint and its neighbours) from each
+    proposal's chain state; the test, run on the host on those compact
+    blocks, marks the rows a removal touches, and the device re-sweeps
+    only those rows on the post-removal graph before min-plus patching the
+    added edges back in — the ``SymmetricAPSP`` algorithm, vectorized over
+    proposals.  An accepted proposal's post-swap rows are selected on the
+    device; no whole state crosses to the host.  Proposals whose affected
+    set exceeds ``full_rebuild_frac`` of the rows (or whose base is
+    disconnected) fall back to a full re-sweep expressed in the same
     vocabulary.  Every ``resync_every`` iterations (and at the end) a full
     re-sweep asserts the incremental state has not drifted.  Pricing is
-    exact integer hop counts either way, so ``delta`` changes wall time
-    only: per seed the trajectory is bit-identical to ``delta=False``.
+    exact integer hop counts, so every accept decision is the one a
+    from-scratch BFS of the proposal graph would give.
+
+    Each chain holds its graph as its orbit list, its chord-edge set and
+    one (n, kmax) neighbour table; the chains start from the warm start's
+    shared ones.  A proposal's table rebuilds only the rows of the swapped
+    edges' endpoints, and the exchange rebuilds a whole table from the
+    best chain's orbits.
 
     Batched proposals are accepted greedily in lockstep order: once a
     chain accepts, the rest of its batch was priced against a stale base
@@ -1123,7 +1131,7 @@ def _replica_polish(
     Every ``exchange_every`` iterations the globally best state replaces the
     worst non-protected chain, exactly like ``sa_search``.
 
-    Under delta pricing the chains' current rows and best snapshots are
+    The chains' current rows and best snapshots are
     two (R, s, n) device arrays split over the replica mesh, each chain's
     rows on the device that prices its proposals.  After each dispatch one
     program (``pallas_sweep.place_states``) takes every chain's accepted
@@ -1151,34 +1159,27 @@ def _replica_polish(
             gamma = math.exp(math.log(t_end / t_start) / n_iter)
             ring_edges = {(i, (i + 1) % n) for i in range(n - 1)} | {(0, n - 1)}
 
-            def adj_of(orbs) -> np.ndarray:
-                a = np.zeros((n, n), dtype=bool)
-                for i, j in ring_edges:
-                    a[i, j] = a[j, i] = True
-                for orb in orbs:
-                    for i, j in orb:
-                        a[i, j] = a[j, i] = True
-                return a
+            def nbr_of(chords) -> np.ndarray:
+                return _nbr_of_edges(n, itertools.chain(ring_edges, chords))
 
+            # all chains start from the warm start's orbits, chords and
+            # table, shared: a move replaces them, never writes them
             start = sorted(start_orbits, key=sorted)
+            chords0 = {e for orb in start for e in orb}
+            nbr0 = nbr_of(chords0)
             chains = [_PolishChain(np.random.default_rng([seed, r]), start,
-                                   adj_of(start), t_start)
+                                   chords0, nbr0, t_start)
                       for r in range(replicas)]
             norm = s * (n - 1)
             dispatches = 1
-            # all chains share the warm start: priced once on each replica
-            # device (the same pricing, so the same rows), it seeds cur/best
-            if delta:
-                nd = pallas_sweep.replica_shards(replicas)
-                tot0, mx0, st0 = pallas_sweep.sharded_delta_state(
-                    np.zeros((nd, s, n), dtype=np.int32),
-                    np.stack([chains[0].nbr] * nd), [np.arange(s)] * nd,
-                    [None] * nd, n, use_pallas=use_pallas)
-                base = best_rows = pallas_sweep.spread_states(st0, replicas)
-                pallas_sweep.prepare_states(base, proposal_batch)
-            else:
-                tot0, mx0 = pallas_sweep.sharded_rows_totals(
-                    np.stack([chains[0].nbr]), s, n, use_pallas=use_pallas)
+            # the warm start is priced once on each replica device (the same
+            # pricing, so the same rows); it seeds cur/best
+            nd = pallas_sweep.replica_shards(replicas)
+            tot0, mx0, st0 = pallas_sweep.sharded_delta_state(
+                np.zeros((nd, s, n), dtype=np.int32), np.stack([nbr0] * nd),
+                [np.arange(s)] * nd, [None] * nd, n, use_pallas=use_pallas)
+            base = best_rows = pallas_sweep.spread_states(st0, replicas)
+            pallas_sweep.prepare_states(base, proposal_batch)
             mpl0 = tot0[0] / norm if mx0[0] < n else float("inf")
             d0 = float(mx0[0]) if mx0[0] < n else float("inf")
             for ch in chains:
@@ -1191,12 +1192,12 @@ def _replica_polish(
             evals_delta = evals_full = 0
             history = [mpl0]
             global_best = (mpl0, d0)
-            nbr_stack = np.empty((bsz,) + chains[0].nbr.shape, dtype=np.int32)
+            nbr_stack = np.empty((bsz,) + nbr0.shape, dtype=np.int32)
             empty = np.empty(0, dtype=np.int64)
             # the lost-parent test's columns: two swapped orbits remove at
             # most 2 * fold edges, so 4 * fold endpoints, each with kmax
             # neighbours; one gather shape per configuration
-            cols = np.zeros((bsz, 4 * fold * (1 + chains[0].nbr.shape[1])),
+            cols = np.zeros((bsz, 4 * fold * (1 + nbr0.shape[1])),
                             dtype=np.int32)
             column_pulls = column_bytes = 0
             place_calls = state_moves = state_move_bytes = 0
@@ -1205,7 +1206,7 @@ def _replica_polish(
             srcs: list = [empty] * bsz
             patches: list = [None] * bsz
             with obs.span("repro.polish.propose"):
-                drawn = []  # delta proposals, tested once their columns are in
+                drawn = []  # proposals, tested once their columns are in
                 for r, ch in enumerate(chains):
                     ch.t *= gamma
                     for m in range(mprop):
@@ -1223,14 +1224,8 @@ def _replica_polish(
                         work_chords = remaining | new_edges
                         removed = sorted(ch.chord_edges - work_chords)
                         added = sorted(work_chords - ch.chord_edges)
-                        if delta:
-                            drawn.append((slot, ch, removed, added, work_list,
-                                          work_chords))
-                        else:
-                            nbr_stack[slot] = tn = ch.trial_nbr(removed, added)
-                            evals_full += 1
-                            proposals[slot] = (removed, added, work_list, work_chords,
-                                               tn)
+                        drawn.append((slot, ch, removed, added, work_list,
+                                      work_chords))
                 if drawn:
                     with obs.span("repro.polish.columns"):
                         cols[:] = 0  # idle slots gather column 0, unread
@@ -1257,15 +1252,10 @@ def _replica_polish(
                         srcs[slot] = np.nonzero(aff)[0]
                         patches[slot] = added
                         evals_delta += 1
-                    proposals[slot] = (removed, added, work_list, work_chords, None)
-            if any(p is not None for p in proposals):
-                if delta:
-                    totals, maxima, states = pallas_sweep.sharded_delta_state(
-                        base, nbr_stack, srcs, patches, n, use_pallas=use_pallas)
-                else:
-                    totals, maxima = pallas_sweep.sharded_rows_totals(
-                        nbr_stack, s, n, use_pallas=use_pallas)
-                    states = None
+                    proposals[slot] = (removed, added, work_list, work_chords)
+            if drawn:
+                totals, maxima, states = pallas_sweep.sharded_delta_state(
+                    base, nbr_stack, srcs, patches, n, use_pallas=use_pallas)
                 dispatches += 1
                 take = np.full(replicas, -1, dtype=np.int32)
                 better = np.zeros(replicas, dtype=bool)
@@ -1284,11 +1274,9 @@ def _replica_polish(
                             if not (dm < 0
                                     or ch.rng.random() < math.exp(-dm / max(ch.t, 1e-12))):
                                 continue
-                            removed, added, work_list, work_chords, tn = proposals[slot]
-                            if tn is None:  # delta slots carry the post-removal table
-                                tn = ch.trial_nbr(removed, added)
-                            ch.commit(removed, added, work_list, work_chords, tn,
-                                      new_mpl, new_d)
+                            removed, added, work_list, work_chords = proposals[slot]
+                            ch.commit(work_list, work_chords,
+                                      ch.trial_nbr(removed, added), new_mpl, new_d)
                             take[r] = m
                             committed = True
                             accepted += 1
@@ -1299,7 +1287,7 @@ def _replica_polish(
                                 if (ch.best_mpl, ch.best_d) < global_best:
                                     global_best = (ch.best_mpl, ch.best_d)
                                     history.append(ch.best_mpl)
-                if delta and (take >= 0).any():
+                if (take >= 0).any():
                     with obs.span("repro.polish.place"):
                         base, best_rows = pallas_sweep.place_states(
                             base, best_rows, states, take, better)
@@ -1315,18 +1303,15 @@ def _replica_polish(
                             ch = chains[worst]
                             ch.orb_list = sorted(chains[gb].best_orbits, key=sorted)
                             ch.chord_edges = {e for orb in ch.orb_list for e in orb}
-                            ch.adj = adj_of(ch.orb_list)
-                            ch.nbr = metrics._nbr_table(ch.adj)
+                            ch.nbr = nbr_of(ch.chord_edges)
                             ch.cur_mpl, ch.cur_d = chains[gb].best_mpl, chains[gb].best_d
-                            if delta:
-                                with obs.span("repro.polish.place"):
-                                    base, moved = pallas_sweep.copy_state(
-                                        base, best_rows, gb, worst)
-                                place_calls += 1
-                                state_moves += moved > 0
-                                state_move_bytes += moved
-            if delta and (it + 1 == n_iter
-                          or (resync_every and (it + 1) % resync_every == 0)):
+                            with obs.span("repro.polish.place"):
+                                base, moved = pallas_sweep.copy_state(
+                                    base, best_rows, gb, worst)
+                            place_calls += 1
+                            state_moves += moved > 0
+                            state_move_bytes += moved
+            if it + 1 == n_iter or (resync_every and (it + 1) % resync_every == 0):
                 _resync_check(base, np.stack([ch.nbr for ch in chains]), n,
                               use_pallas)
                 dispatches += 1
@@ -1372,7 +1357,6 @@ def large_search(
     engine: str | None = None,
     replicas: int = 1,
     exchange_every: int = 50,
-    delta: bool = True,
     proposal_batch: int = 1,
     resync_every: int = 64,
     polish_iters: int | None = None,
@@ -1394,12 +1378,11 @@ def large_search(
     — the ``sa_search`` semantics) whose proposals are priced in one
     ``shard_map`` dispatch per iteration, each device sweeping its replicas'
     packed-frontier BFS locally — the Pallas VMEM kernel when
-    ``engine="pallas"``, its jitted jnp twin otherwise.  By default the
-    dispatch prices **incrementally** (``delta=True``: affected-rows-only
-    re-sweep plus min-plus patch, the device twin of ``SymmetricAPSP``) with
-    a periodic full-sweep drift guard every ``resync_every`` iterations;
-    ``delta=False`` forces the full re-sweep of every proposal, bit-identical
-    per seed but slower.  ``proposal_batch`` prices M candidate swaps per
+    ``engine="pallas"``, its jitted jnp twin otherwise.  The dispatch prices
+    **incrementally** (affected-rows-only re-sweep plus min-plus patch, the
+    device twin of ``SymmetricAPSP``) with a periodic full-sweep drift
+    guard every ``resync_every`` iterations.  ``proposal_batch`` prices M
+    candidate swaps per
     chain per dispatch (accepted greedily in lockstep order) to amortize
     dispatch overhead; ``polish_iters`` overrides the polish iteration count
     derived from ``budget`` (it applies to the single-replica symmetric
@@ -1441,7 +1424,7 @@ def large_search(
             n, k, seed=seed, n_iter=n_polish,
             fold=fold, start_orbits=orbits, engine=engine,
             replicas=replicas, exchange_every=exchange_every,
-            delta=delta, proposal_batch=proposal_batch,
+            proposal_batch=proposal_batch,
             resync_every=resync_every)
     else:
         res_s = symmetric_sa_search(

@@ -8,6 +8,8 @@ diameter that a from-scratch ``metrics.apsp`` recompute yields: on the delta
 path, the forced-full path, the C kernel and the pure numpy fallback alike,
 including swaps that disconnect the graph.
 """
+import itertools
+
 import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
@@ -35,6 +37,33 @@ def _random_swap(ev, ring_mask, rng):
     if ev.adj[p1] or ev.adj[p2]:
         return None
     return [(a, b), (c, d)], [p1, p2]
+
+
+# redraws of an invalid swap before a step of a property test gives up
+_SWAP_TRIES = 64
+
+
+def _redrawn_swap(ev, ring_mask, rng):
+    """``_random_swap``, redrawn while invalid, up to ``_SWAP_TRIES``
+    draws; None only if every draw was invalid."""
+    for _ in range(_SWAP_TRIES):
+        swap = _random_swap(ev, ring_mask, rng)
+        if swap is not None:
+            return swap
+    return None
+
+
+def _has_valid_swap(adj, ring_mask) -> bool:
+    """Whether any valid 2-edge swap exists on ``adj`` (every chord pair,
+    both rewirings)."""
+    iu, ju = np.where(np.triu(adj & ~ring_mask))
+    for e1, e2 in itertools.combinations(range(len(iu)), 2):
+        a, b, c, d = int(iu[e1]), int(ju[e1]), int(iu[e2]), int(ju[e2])
+        if len({a, b, c, d}) == 4 and any(
+                not adj[p1] and not adj[p2]
+                for p1, p2 in (((a, c), (b, d)), ((a, d), (b, c)))):
+            return True
+    return False
 
 
 def _reference(adj, removed, added):
@@ -68,10 +97,13 @@ def test_delta_matches_full_recompute(inst, swap_seed):
         return
     rng = np.random.default_rng(swap_seed)
     ring_mask = _swap_space(n)
+    if not _has_valid_swap(g.adjacency(), ring_mask):
+        return  # no swap to price on this instance
     ev = metrics.IncrementalAPSP(g.adjacency().copy(), full_rebuild_frac=1.1)
     ev_full = metrics.IncrementalAPSP(g.adjacency().copy(), force_full=True)
     for _ in range(6):
-        swap = _random_swap(ev, ring_mask, rng)
+        # an invalid draw is redrawn, so that every example prices swaps
+        swap = _redrawn_swap(ev, ring_mask, rng)
         if swap is None:
             continue
         removed, added = swap

@@ -127,8 +127,10 @@ def test_bfs_sweep_batched_stack():
 
 
 def test_sharded_rows_totals_match_host():
-    """The shard_map-batched (total, max) pricing equals host BFS sums, on
-    both the Pallas and jnp device paths."""
+    """The shard_map-batched (total, max) pricing of whole graphs (every
+    representative row swept, no patch: the polish's warm start and
+    full-rebuild slots) equals host BFS sums, on both the Pallas and jnp
+    device paths."""
     from repro.core import metrics
     from repro.core.engines import pallas_sweep
     from repro.core.graphs import circulant
@@ -139,10 +141,12 @@ def test_sharded_rows_totals_match_host():
     want = np.stack([metrics.bitset_bfs_rows(nbrs[r], np.arange(m), n)
                      for r in range(2)])
     for use_pallas in (True, False):
-        tot, mx = pallas_sweep.sharded_rows_totals(nbrs, m, n,
-                                                   use_pallas=use_pallas)
+        tot, mx, state = pallas_sweep.sharded_delta_state(
+            np.zeros((2, m, n), np.int32), nbrs, [np.arange(m)] * 2,
+            [None] * 2, n, use_pallas=use_pallas)
         assert np.array_equal(tot, want.sum((1, 2), dtype=np.int64)), use_pallas
         assert np.array_equal(mx, want.max((1, 2))), use_pallas
+        assert np.array_equal(np.asarray(state), want), use_pallas
 
 
 def _orbit_graph(fold: int, s: int, chords: int, odd_kmax: bool, seed: int,

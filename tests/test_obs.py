@@ -178,7 +178,7 @@ def test_dispatch_run_counts_the_sweep_fold(trace_dir, engine, fold, want):
     with jax.profiler.trace(trace_dir):
         res = search._replica_polish(
             n, 6, seed=3, n_iter=3, fold=fold, start_orbits=orbits,
-            engine=engine, replicas=2, exchange_every=5, delta=True)
+            engine=engine, replicas=2, exchange_every=5)
     runs = repro_events(trace_dir)["repro.dispatch.run"]
     assert len(runs) == res.device_dispatches > 0
     assert [r[2] for r in runs] == [{"sweep_fold": want}] * len(runs)

@@ -297,20 +297,18 @@ def test_replica_polish_pallas_and_jnp_device_paths_identical():
 def test_replica_polish_multi_device_invariant(devices8, replicas):
     """Sharding the replica axis over real (forced-host) devices changes
     the placement, never the math: 4 devices reproduce the 1-device run,
-    with the delta dispatch and with the full sweep, whether each device
-    holds one chain or four."""
+    whether each device holds one chain or four."""
     kw = dict(seed=0, budget=10, fold=4, replicas=replicas, exchange_every=10)
     res = search.large_search(48, 4, **kw)
     out = devices8(f"""
         from repro.core import search
-        for delta in (True, False):
-            res = search.large_search(48, 4, delta=delta, **{kw!r})
-            print(res.mpl, res.diameter, res.accepted, res.history,
-                  hash(res.graph.edges))
+        res = search.large_search(48, 4, **{kw!r})
+        print(res.mpl, res.diameter, res.accepted, res.history,
+              hash(res.graph.edges))
     """, n_devices=4)
     want = (f"{res.mpl} {res.diameter} {res.accepted} {res.history} "
             f"{hash(res.graph.edges)}")
-    assert out.strip().splitlines() == [want, want]
+    assert out.strip().splitlines() == [want]
 
 
 def test_replica_polish_state_split_over_devices(devices8):
@@ -378,36 +376,77 @@ def test_replica_polish_state_split_over_devices(devices8):
     assert tally["state_move_bytes"] == crossed * s * n * 4
 
 
-def _polish_pair(n, k, fold, seed, replicas, engine=None, n_iter=25, **kw):
-    """(delta, full) `_replica_polish` runs from the same circulant warm
-    start — the property under test is bit-identical trajectories."""
-    from repro.core.search import _circulant_orbits, _replica_polish
+def _host_rows(nbr, added, s, sentinel):
+    """Hop distances from sources 0..s-1 of the graph of the padded table
+    ``nbr`` plus the undirected edges ``added``, by a host BFS
+    (``metrics.apsp_hops``), ``sentinel`` where a vertex is unreached."""
+    n, kmax = nbr.shape
+    adj = np.zeros((n, n), dtype=bool)
+    u, v = np.repeat(np.arange(n), kmax), nbr.ravel()
+    adj[u[v >= 0], v[v >= 0]] = True
+    for a, b in added or ():
+        adj[a, b] = adj[b, a] = True
+    return metrics.apsp_hops(adj, sentinel)[:s]
+
+
+def _checked_polish(n, k, fold, seed, replicas, n_iter=25, **kw):
+    """One `_replica_polish` run from a circulant warm start, every device
+    dispatch checked against the host: for every slot, the returned total
+    and maximum equal a host BFS of the graph the slot priced, its table
+    ``nbrs[i]`` (the post-removal table where it has a patch, the
+    post-swap one where not) plus the patch's added edges.  Equal totals
+    and maxima fix the accept decisions, so the walk is the one exact
+    pricing gives.  Returns the result and a log: the dispatches, the
+    proposals they priced (slots with rows to re-sweep or a patch, outside
+    the warm start and the drift guard) and the drift-guard re-sweeps."""
+    from repro.core.engines import pallas_sweep
 
     offs = (2, 9) if k == 4 else (2, 9, 17)
-    orbits = _circulant_orbits(n, n // fold, offs)
-    run = lambda delta: _replica_polish(  # noqa: E731
-        n, k, seed=seed, n_iter=n_iter, fold=fold, start_orbits=orbits,
-        engine=engine, replicas=replicas, exchange_every=10, delta=delta, **kw)
-    return run(True), run(False)
+    orbits = search._circulant_orbits(n, n // fold, offs)
+    dispatch, resync = pallas_sweep.sharded_delta_state, search._resync_check
+    calls = []  # per dispatch: the slots that priced something, or None
+
+    def checked(base, nbrs, sources_list, patches, sentinel, **kw):
+        totals, maxima, state = dispatch(base, nbrs, sources_list, patches,
+                                         sentinel, **kw)
+        for i, (nb, added) in enumerate(zip(nbrs, patches)):
+            rows = _host_rows(nb, added, base.shape[1], sentinel)
+            assert totals[i] == rows.sum(dtype=np.int64), (len(calls), i)
+            assert maxima[i] == rows.max(), (len(calls), i)
+        calls.append(sum(len(src) > 0 or p is not None
+                         for src, p in zip(sources_list, patches)))
+        return totals, maxima, state
+
+    def resync_w(*a, **kw):
+        resync(*a, **kw)
+        calls[-1] = None
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(pallas_sweep, "sharded_delta_state", checked)
+        mp.setattr(search, "_resync_check", resync_w)
+        res = search._replica_polish(
+            n, k, seed=seed, n_iter=n_iter, fold=fold, start_orbits=orbits,
+            engine=None, replicas=replicas, exchange_every=10, **kw)
+    return res, dict(dispatches=len(calls),
+                     priced=sum(c for c in calls[1:] if c is not None),
+                     resyncs=calls.count(None))
 
 
 @pytest.mark.parametrize("seed", [0, 3])
 @pytest.mark.parametrize("replicas", [2, 3])
 def test_replica_polish_delta_matches_full_sweep_trajectory(seed, replicas):
-    """Delta pricing (affected-rows re-sweep + min-plus patch) is bit-
-    identical to the full-sweep dispatch per seed and replica count: exact
-    integer hop counts mean the accept decisions — hence the trajectory,
-    history and final graph — cannot diverge.  engine=None resolves through
-    the registry, so the CI engine matrix re-runs this under every
-    REPRO_ENGINE (the Pallas kernel path included)."""
-    d, f = _polish_pair(64, 4, 4, seed, replicas)
-    assert d.graph.edges == f.graph.edges
-    assert d.mpl == f.mpl and d.diameter == f.diameter
-    assert d.history == f.history and d.accepted == f.accepted
+    """Delta pricing (affected-rows re-sweep + min-plus patch) prices every
+    slot of every dispatch exactly as a host BFS of its graph does, per
+    seed and replica count: exact integer hop counts mean the accept
+    decisions — hence the trajectory, history and final graph — are those
+    of a full re-sweep.  engine=None resolves through the registry, so the
+    CI engine matrix re-runs this under every REPRO_ENGINE (the Pallas
+    kernel path included)."""
+    res, log = _checked_polish(64, 4, 4, seed, replicas)
     # the observability contract: the split reports which pricer ran
-    assert d.evals_delta + d.evals_full == f.evals_full
-    assert d.evals_delta > 0 and f.evals_delta == 0
-    assert d.device_dispatches > 0 and f.device_dispatches > 0
+    assert res.evals_delta + res.evals_full == log["priced"]
+    assert res.evals_delta > 0
+    assert res.device_dispatches == log["dispatches"] > 0
 
 
 def test_replica_polish_delta_pallas_matches_jnp_twin():
@@ -418,7 +457,7 @@ def test_replica_polish_delta_pallas_matches_jnp_twin():
     orbits = _circulant_orbits(48, 12, (2, 9))
     run = lambda eng: _replica_polish(  # noqa: E731
         48, 4, seed=0, n_iter=20, fold=4, start_orbits=orbits, engine=eng,
-        replicas=2, exchange_every=10, delta=True)
+        replicas=2, exchange_every=10)
     a, b = run("pallas"), run("bitset")
     assert a.graph.edges == b.graph.edges
     assert a.mpl == b.mpl and a.history == b.history
@@ -434,7 +473,7 @@ def test_replica_polish_delta_pallas_matches_jnp_twin_at_fold_8():
     orbits = _circulant_orbits(96, 12, (2, 9))
     run = lambda eng: _replica_polish(  # noqa: E731
         96, 4, seed=1, n_iter=20, fold=8, start_orbits=orbits, engine=eng,
-        replicas=2, exchange_every=10, delta=True)
+        replicas=2, exchange_every=10)
     a, b = run("pallas"), run("bitset")
     assert a.device_dispatches > 0 and a.evals_delta > 0
     assert a.graph.edges == b.graph.edges
@@ -444,19 +483,19 @@ def test_replica_polish_delta_pallas_matches_jnp_twin_at_fold_8():
 
 def test_replica_polish_proposal_batch():
     """proposal_batch=M prices M swaps per chain per dispatch and accepts
-    greedily in lockstep order: M=1 reproduces the unbatched trajectory
-    verbatim (it *is* the unbatched loop), larger M is deterministic,
-    prices M proposals per chain per iteration, and still never degrades
-    below the warm start."""
-    d1, f1 = _polish_pair(64, 4, 4, 0, 2, proposal_batch=1)
-    assert d1.graph.edges == f1.graph.edges and d1.history == f1.history
-    b1 = _polish_pair(64, 4, 4, 0, 2, proposal_batch=3)[0]
-    b2 = _polish_pair(64, 4, 4, 0, 2, proposal_batch=3)[0]
+    greedily in lockstep order: M=1 (the unbatched loop) prices every
+    dispatch exactly, larger M is deterministic, prices M proposals per
+    chain per iteration, and still never degrades below the warm start."""
+    d1, log1 = _checked_polish(64, 4, 4, 0, 2, proposal_batch=1)
+    assert d1.evals_delta + d1.evals_full == log1["priced"] > 0
+    b1, log3 = _checked_polish(64, 4, 4, 0, 2, proposal_batch=3)
+    b2 = _checked_polish(64, 4, 4, 0, 2, proposal_batch=3)[0]
     assert b1.graph.edges == b2.graph.edges and b1.history == b2.history
+    assert b1.evals_delta + b1.evals_full == log3["priced"]
     assert b1.evals_delta + b1.evals_full > d1.evals_delta + d1.evals_full
     assert b1.mpl <= d1.history[0]  # warm-start MPL never degrades
     with pytest.raises(ValueError, match="proposal_batch"):
-        _polish_pair(64, 4, 4, 0, 2, proposal_batch=0)
+        _checked_polish(64, 4, 4, 0, 2, proposal_batch=0)
 
 
 def test_sharded_delta_state_disconnect_and_recovery_exact():
@@ -511,7 +550,7 @@ def test_replica_polish_resync_drift_guard():
     orbits = _circulant_orbits(64, 16, (2, 9))
     res = _replica_polish(64, 4, seed=0, n_iter=16, fold=4,
                           start_orbits=orbits, engine="bitset", replicas=2,
-                          exchange_every=8, delta=True, resync_every=4)
+                          exchange_every=8, resync_every=4)
     assert res.mpl < float("inf")  # every in-walk resync was clean
 
     from repro.core.graphs import circulant
@@ -595,16 +634,89 @@ def test_column_pull_lost_parent_matches_full_state(fold, offsets):
 @pytest.mark.parametrize("seed", [0, 1, 2])
 def test_replica_polish_delta_equals_full_at_the_cells_shape(seed):
     """At the benchmark cells' shape (4 replicas, 2 proposals each) the
-    delta polish, its state on the device, returns the full sweep's
-    result, through a replica exchange (after iteration 10) and drift-guard
-    re-sweeps (after 5, 10 and 12)."""
-    import dataclasses
+    delta polish, its state on the device, prices every dispatch as a host
+    BFS does, through a replica exchange (after iteration 10) and
+    drift-guard re-sweeps (after 5, 10 and 12), and returns the host's
+    pricing of the graph it returns."""
+    res, log = _checked_polish(64, 4, 4, seed, 4, n_iter=12,
+                               proposal_batch=2, resync_every=5)
+    assert res.evals_delta > 0 and log["resyncs"] == 3
+    assert res.evals_delta + res.evals_full == log["priced"]
+    s, n = 16, 64
+    rows = metrics.apsp_hops(res.graph.adjacency())[:s]
+    assert res.mpl == rows.sum(dtype=np.int64) / (s * (n - 1))
+    assert res.diameter == rows.max()
 
-    d, f = _polish_pair(64, 4, 4, seed, 4, n_iter=12, proposal_batch=2,
-                        resync_every=5)
-    counts = dict(evals_delta=0, evals_full=0, device_dispatches=0)
-    assert dataclasses.replace(d, **counts) == dataclasses.replace(f, **counts)
-    assert d.evals_delta > 0
+
+@pytest.mark.parametrize("fold", [4, 8])
+def test_polish_chain_tables_match_the_graph(fold, monkeypatch):
+    """Each polish chain's one graph table is its graph: after orbit-swap
+    commits, and after an exchange rebuild, ``nbr`` equals
+    ``metrics._nbr_table`` of the ring plus its orbit list.  A swap keeps
+    one endpoint of each orbit it removes, so its removed and added edges
+    share endpoints; the first check takes such a draw on its own."""
+    from repro.core.engines import pallas_sweep
+    from repro.core.graphs import from_edges
+
+    n, k, s = 12 * fold, 6, 12
+    ring_edges = {(i, (i + 1) % n) for i in range(n - 1)} | {(0, n - 1)}
+
+    def want(ch):
+        chords = {e for orb in ch.orb_list for e in orb}
+        assert chords == ch.chord_edges
+        return metrics._nbr_table(from_edges(n, ring_edges | chords).adjacency())
+
+    orbits = sorted(search._circulant_orbits(n, s, (2, 5)), key=sorted)
+    chords = {e for orb in orbits for e in orb}
+    ch = search._PolishChain(np.random.default_rng(fold), orbits, chords,
+                             search._nbr_of_edges(n, ring_edges | chords), 1.0)
+    assert np.array_equal(ch.nbr, want(ch))
+    while True:  # one draw, then commit it
+        mv = search._draw_orbit_swap(ch.rng, ch.orb_list, ch.chord_edges,
+                                     ring_edges, n, s, fold)
+        if mv is not None:
+            break
+    i1, i2, no1, no2, new_edges, remaining = mv
+    work_list = [o for i, o in enumerate(ch.orb_list) if i not in (i1, i2)]
+    work_chords = remaining | new_edges
+    removed = sorted(ch.chord_edges - work_chords)
+    added = sorted(work_chords - ch.chord_edges)
+    assert {x for e in removed for x in e} & {x for e in added for x in e}
+    before = ch.nbr.copy()
+    ch.commit(work_list + [no1, no2], work_chords,
+              ch.trial_nbr(removed, added), 0.0, 0.0)
+    assert np.array_equal(ch.nbr, want(ch))
+    touched = sorted({x for e in (*removed, *added) for x in e})
+    rest = np.setdiff1d(np.arange(n), touched)
+    assert np.array_equal(ch.nbr[rest], before[rest])
+
+    # through the polish: every chain's table at each exchange and at the end
+    made, rebuilt = [], []
+
+    class Recorded(search._PolishChain):
+        __slots__ = ()
+
+        def __init__(self, *a):
+            super().__init__(*a)
+            made.append(self)
+
+    copy = pallas_sweep.copy_state
+
+    def copy_w(dst, src, i, j):
+        rebuilt.append(j)
+        for c in made:
+            assert np.array_equal(c.nbr, want(c))
+        return copy(dst, src, i, j)
+
+    monkeypatch.setattr(search, "_PolishChain", Recorded)
+    monkeypatch.setattr(pallas_sweep, "copy_state", copy_w)
+    res = search._replica_polish(
+        n, k, seed=1, n_iter=12, fold=fold,
+        start_orbits=search._circulant_orbits(n, s, (2, 5)), engine=None,
+        replicas=3, exchange_every=4, proposal_batch=2)
+    assert rebuilt and res.accepted > 0 and len(made) == 3
+    for c in made:
+        assert np.array_equal(c.nbr, want(c))
 
 
 def test_pallas_interpret_env_override(monkeypatch):
