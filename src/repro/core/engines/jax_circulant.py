@@ -1,11 +1,14 @@
 """``engine="jax"`` — the jitted batched circulant pricer.
 
-``circulant_search`` prices candidate offset sets; this module is the same
-packed frontier sweep as the sequential ``search._circulant_profile``, jitted
-and batched over candidate offset sets (each candidate's frontier is one
-row; the while_loop advances every candidate's BFS level in lock step).
-Exact integer hop counts, so the values — and therefore the hillclimb
-trajectory — are identical to the numpy path.
+``circulant_search`` prices candidate offset sets; this module runs the same
+BFS from vertex 0 as the sequential ``search._circulant_profile``, jitted and
+batched over candidate offset sets: each candidate's frontier is one row,
+and the while_loop advances every candidate's BFS level in lock step.  A
+level rotates each row's frontier by that row's own shifts with static
+rolls by powers of two, chosen per row by the bits of the shift, so the
+loop holds no gather and is exact for every n.  Exact integer hop counts,
+so the values — and therefore the hillclimb trajectory — are identical to
+the numpy path.
 """
 from __future__ import annotations
 
@@ -34,30 +37,39 @@ def jax_modules():
 
 
 def _jax_sweep(n: int, m: int):
-    """Jitted batched frontier sweep for (chunk, m) shift arrays on C_n.
+    """Jitted batched BFS from vertex 0 of C_n for (chunk, m) shift arrays.
 
     Returns a function shifts -> (total_hops, diameter, connected) per
-    candidate row.  Shift lists may contain duplicates (padding) — OR-ing a
-    frontier with itself is a no-op, so the counts stay exact.
+    candidate row.  A level ORs the frontier rotated by each of the row's m
+    shifts.  The rotations are built from static rolls, with no gather: each
+    shift is written in ``(n - 1).bit_length()`` bits, and the frontier,
+    copied onto an (m,) axis, is rolled by 2**j wherever bit j of that
+    shift is set.  Rolls compose additively mod n, so the rotation is exact
+    for every n, not only powers of two, and a level is that many
+    elementwise steps on a (chunk, m, n) array.  Shift lists may contain
+    duplicates (padding): OR-ing a frontier with itself is a no-op, so the
+    counts stay exact.
     """
     key = (n, m)
     fn = _CACHE.get(key)
     if fn is not None:
         return fn
     jax, jnp = jax_modules()
+    nbits = (n - 1).bit_length()  # every shift lies in [0, n): 2**j < n
 
     def sweep(shifts):
         b = shifts.shape[0]
-        idx = (jnp.arange(n)[None, None, :] - shifts[:, :, None]) % n  # (b, m, n)
+        bits = [((shifts >> j) & 1).astype(bool)[:, :, None]  # (b, m, 1)
+                for j in range(nbits)]
         reach0 = jnp.zeros((b, n), bool).at[:, 0].set(True)
         zeros = jnp.zeros((b,), jnp.int32)
 
         def body(st):
             d, total, diam, reach, frontier = st
-            nxt = jnp.zeros_like(frontier)
-            for i in range(m):  # static unroll: m <= 2k shifts
-                nxt = nxt | jnp.take_along_axis(frontier, idx[:, i, :], axis=1)
-            newf = nxt & ~reach
+            x = jnp.broadcast_to(frontier[:, None, :], (b, m, n))
+            for j in range(nbits):  # x[i] -> x[i - 2**j] where bit j is set
+                x = jnp.where(bits[j], jnp.roll(x, 1 << j, axis=-1), x)
+            newf = x.any(1) & ~reach
             cnt = newf.sum(1, dtype=jnp.int32)
             d = d + 1
             return (d, total + d * cnt, jnp.where(cnt > 0, d, diam),

@@ -572,10 +572,12 @@ def circulant_search(
 
     ``engine`` selects the candidate pricer (resolved and validated by the
     ``core.engines`` registry): ``"numpy"`` prices candidates one at a
-    time; ``"jax"`` batches each position sweep through a jitted packed
-    frontier sweep (``engines.jax_circulant``) — the accelerator path for
-    N >= 8192 offset batches.  ``"auto"`` picks ``"jax"`` when jax imports
-    and n >= 4096, ``"numpy"`` otherwise.  The pricers return identical
+    time; ``"jax"`` prices each position sweep's candidates as one batch
+    in a jitted BFS (``engines.jax_circulant``) that rotates every
+    candidate's frontier by its shifts with static rolls by powers of two,
+    exact for any n — the accelerator path for large offset batches.
+    ``"auto"`` picks ``"jax"`` when jax imports and n >= 4096, ``"numpy"``
+    otherwise.  The pricers return identical
     values and candidates are accepted in the same order, so the trajectory
     (and the result) is bit-identical across engines at a given seed.
     """

@@ -164,13 +164,18 @@ def test_delta_step_compiles_for_v5e_at_fold_4(topo):
     assert _has_sweep_kernel(compiled)
 
 
-def test_jax_circulant_sweep_compiles_for_v5e(one_chip):
+# m shifts a candidate: k = 6 (three offsets and their negatives), k = 8
+@pytest.mark.parametrize("m", [6, 8])
+def test_jax_circulant_sweep_compiles_for_v5e(one_chip, m):
+    """The circulant pricer's level loop rotates frontiers with static
+    rolls: the compiled program holds no gather, which the TPU runs near
+    element by element."""
     from repro.core.engines import jax_circulant
 
-    m = 6  # k = 6: three offsets and their negatives
     fn = jax_circulant._jax_sweep(N, m)
     compiled = fn.lower(_sds((jax_circulant.CHUNK, m), one_chip)).compile()
     assert compiled.memory_analysis().peak_memory_in_bytes > 0
+    assert "gather" not in compiled.as_text()
 
 
 COLLECTIVES = ("all-gather", "all-reduce", "collective-permute", "all-to-all",
